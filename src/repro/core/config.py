@@ -71,7 +71,7 @@ class EngineConfig:
     pipeline_depth: int | str | None = None  # executor window; int or "auto"
     prefetch: bool | None = None  # stage missed host rows ahead of their gather
     use_kernel: bool | None = None  # route gathers through the Pallas kernel
-    gather_buffers: int | None = None  # kernel VMEM row-tile slots
+    gather_buffers: int | None = None  # kernel row copies in flight
     dedup: bool | None = None  # sorted-unique frontier gathers (sampling mode)
     chunk_size: int | None = None  # layer-wise node-range chunk (layerwise mode)
     # Online cache refresh (runtime/cache_refresh.py), inline to avoid a

@@ -67,7 +67,7 @@ class PreparedPipeline:
     # per run; outputs and hit accounting are knob-invariant):
     prefetch: bool = False  # stage missed host rows for batch i+1 during batch i's compute
     use_kernel: bool = False  # route gathers through the Pallas cached_gather kernel
-    gather_buffers: int = 2  # kernel VMEM row-tile slots (1 serial, 2 double buffered)
+    gather_buffers: int = 2  # kernel row copies in flight (1 serial, 2 double buffered)
     dedup: bool = False  # gather/prefetch/model on sorted-unique frontiers only
 
 

@@ -126,9 +126,9 @@ class Tracer:
         self._next_flow = itertools.count(1)
         self._annotate: Callable[[str], Any] | None = None
         if jax_annotations:
-            from repro.utils.jax_compat import trace_annotation_compat
+            import jax.profiler
 
-            self._annotate = trace_annotation_compat()
+            self._annotate = jax.profiler.TraceAnnotation
         self._meta(0, "process_name", {"name": process_name})
 
     # -- time ----------------------------------------------------------

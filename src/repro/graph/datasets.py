@@ -11,6 +11,7 @@ a configurable node-count factor.  Generation is deterministic per seed.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 
 import numpy as np
 
@@ -80,7 +81,9 @@ def load_dataset(
     fast); ``max_nodes`` caps it (papers100M at 1% would still be 1.1M).
     """
     spec = DATASETS[name]
-    rng = np.random.default_rng(seed + hash(name) % (2**31))
+    # A stable digest of the name (``hash`` of a str is salted per process),
+    # so one ``seed`` builds one graph in every process.
+    rng = np.random.default_rng(seed + zlib.crc32(name.encode()))
     n = max(int(spec.num_nodes * scale), 64)
     if max_nodes is not None:
         n = min(n, max_nodes)

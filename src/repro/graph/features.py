@@ -90,6 +90,25 @@ class FeatureStore:
             object.__setattr__(self, "_host_np", cached)
         return cached
 
+    def kernel_tables(self) -> tuple[jax.Array, jax.Array]:
+        """``(hot, host)`` in the gather kernel's lane layout (built lazily,
+        once per store; the host copy is shared with derived stores).
+
+        The kernel reads tables padded to whole 128-lane groups
+        (:func:`~repro.kernels.cached_gather.kernel.lane_layout`); building
+        them here keeps that pad off every gather call."""
+        from repro.kernels.cached_gather.kernel import lane_layout
+
+        host = getattr(self, "_host_lanes", None)
+        if host is None:
+            host = lane_layout(self.host_table)
+            object.__setattr__(self, "_host_lanes", host)
+        hot = getattr(self, "_hot_lanes", None)
+        if hot is None:
+            hot = lane_layout(self.hot_table)
+            object.__setattr__(self, "_hot_lanes", hot)
+        return hot, host
+
     def position_np(self) -> np.ndarray:
         """Host-memory mirror of ``position_map`` (cached lazily) — lets
         the prefetch stage find the missed rows without a device round
@@ -208,9 +227,9 @@ class FeatureStore:
     ) -> tuple[jax.Array, jax.Array]:
         """Two-source gather. Returns ``(features[S, F], hit[S])``.
 
-        ``use_kernel=True`` routes through the double-buffered Pallas
-        ``cached_gather`` kernel (compiled on TPU, interpret mode
-        elsewhere) with ``gather_buffers`` VMEM row-tile slots.
+        ``use_kernel=True`` routes through the Pallas ``cached_gather``
+        kernel (compiled on TPU, interpret mode on the CPU) over
+        :meth:`kernel_tables`, with ``gather_buffers`` row copies in flight.
 
         ``prefetched`` (from :meth:`prefetch_misses`) replaces the host
         table as the miss source: miss rows come from the already-staged
@@ -242,37 +261,32 @@ class FeatureStore:
         hit = pos >= 0
         s = indices.shape[0]
         if use_kernel:
-            from repro.kernels.cached_gather.kernel import cached_gather, cached_gather_blocks
+            from repro.kernels.cached_gather.kernel import (
+                cached_gather,
+                cached_gather_blocks,
+                lane_layout,
+            )
 
+            hot_src, host_src = self.kernel_tables()
             if prefetched is None:
-                host_src, host_idx = self.host_table, indices
+                host_idx = indices
             elif prefetched.idx is None:  # all-miss: the pack is row-aligned
-                host_src = prefetched.rows
+                host_src = lane_layout(prefetched.rows)
                 host_idx = jnp.arange(s, dtype=jnp.int32)
             else:
                 # Address the staged pack directly through its inverse map
                 # — no dense [S, F] miss-source rebuild on the gather
                 # stage.  Hit rows point at pack slot 0, which the DMA
                 # kernel never reads (the hit branch copies the hot row).
-                host_src, host_idx = prefetched.rows, prefetched.pack_pos
+                host_src, host_idx = lane_layout(prefetched.rows), prefetched.pack_pos
+            kw = dict(feat_dim=self.feat_dim, gather_buffers=gather_buffers)
             if row_block is not None and row_block > 1:
-                return (
-                    cached_gather_blocks(
-                        self.hot_table,
-                        host_src,
-                        host_idx,
-                        pos,
-                        row_block=row_block,
-                        gather_buffers=gather_buffers,
-                    ),
-                    hit,
+                out = cached_gather_blocks(
+                    hot_src, host_src, host_idx, pos, row_block=row_block, **kw
                 )
-            return (
-                cached_gather(
-                    self.hot_table, host_src, host_idx, pos, gather_buffers=gather_buffers
-                ),
-                hit,
-            )
+            else:
+                out = cached_gather(hot_src, host_src, host_idx, pos, **kw)
+            return out, hit
         safe_pos = jnp.maximum(pos, 0)
         cached = self.hot_table[jnp.minimum(safe_pos, self.hot_table.shape[0] - 1)]
         if prefetched is None:
@@ -474,6 +488,8 @@ def refresh_feature_cache(
     # position map is already known host-side — no device round trip.
     object.__setattr__(new_store, "_host_np", features)
     object.__setattr__(new_store, "_position_np", new_pos_np)
+    if hasattr(store, "_host_lanes"):
+        object.__setattr__(new_store, "_host_lanes", store._host_lanes)
     return new_store, FeatureRefreshStats(
         rows_kept=int(kept_nodes.shape[0]),
         rows_inserted=int(inserted_nodes.shape[0]),

@@ -3,7 +3,7 @@
 Three kernels, each with a jit'd wrapper (ops.py) and a pure-jnp oracle
 (ref.py) that tests sweep shapes/dtypes against in interpret mode:
 
-  cached_gather/    DCI's two-source feature-row gather (scalar-prefetched
+  cached_gather/    DCI's two-source feature-row gather (scalar-memory
                     position map; hit -> hot table, miss -> full table)
   seg_agg/          padded-neighborhood aggregation (GNN sum/mean)
   flash_attention/  blocked online-softmax attention with sliding-window

@@ -1,31 +1,28 @@
-"""Pallas TPU kernel: DCI's two-source cached row gather, double buffered.
+"""Pallas TPU kernel: DCI's two-source cached row gather.
 
-TPU adaptation of the paper's cache-hit feature load (DESIGN.md §3): the
-row id (``indices``) and cache slot (``positions``) arrays are *scalar
-prefetched* — Pallas knows them before any tile DMA, so the kernel issues
-exactly one manual HBM→VMEM copy per feature-row tile from the right
-source (hot cache on a hit, full host table on a miss), never both.
+TPU adaptation of the paper's cache-hit feature load (DESIGN.md §3): for
+each requested row the kernel reads the row id (``indices``) and cache slot
+(``positions``) from scalar memory and issues exactly one HBM→VMEM copy
+from the right source (hot cache on a hit, full host table on a miss),
+never both.
 
-The copy schedule is double buffered (``gather_buffers`` VMEM row-tile
-slots, default 2): row ``i+1``'s HBM→VMEM copy is started while row
-``i``'s tile is being written back, so DMA latency hides behind the
-select/write of the previous row — the same overlap the staged batch
-executor (runtime/pipeline.py) applies one level up across whole batches.
-Completed tiles are written straight into the output batch buffer with a
-VMEM→HBM copy (no intermediate per-source partitions, no concat); a slot
-is only reused once its previous write-back has drained.
+Layout.  Both tables are read in *lane layout* (:func:`lane_layout`): the
+feature axis zero-padded to ``k = ceil(F / 128)`` lane groups and viewed
+as ``[R * k, 128]``, so one table row is ``k`` consecutive 128-lane rows.
+The TPU compiler accepts a DMA of any run of rows of a 128-lane-wide HBM
+array, but refuses a single row of a wider one (the (8, 128) tiling) and a
+feature slice that is not a multiple of 128 lanes.  Building the padded
+view once, at store build time, keeps the per-call pad off the hot path.
 
-Three scalar operands are prefetched: raw positions (hit test), clamped
-positions (safe hot addressing), clamped indices (host addressing).  The
-feature axis is tiled at up to 512 lanes (multiples of the 128-lane VREG
-width) and forms the grid; rows are walked by an inner loop so the slot
-rotation lives in one program.
+Grid.  Rows are tiled over the grid, ``TILE_ROWS`` per step.  The int32
+index operands are blocked into scalar memory one tile at a time (all S
+rows would overflow it at real frontier sizes), and each step's rows are
+copied straight into that step's output VMEM block, which Pallas writes
+back while the next step gathers.  Within a step, up to ``gather_buffers``
+row copies are kept in flight (1 = serial copies, 2 = double buffering).
 
-``interpret=None`` resolves by backend: compiled on TPU, interpret mode
-elsewhere (this CPU container).  Older JAX releases lack DMA semantics in
-interpret mode; :func:`dma_supported` probes once and ``cached_gather``
-falls back to the select-based single-buffered kernel
-(:func:`cached_gather_select`) so the op keeps working there.
+``interpret=None`` resolves by backend: compiled on TPU, interpret mode on
+the CPU, where the tests run.
 """
 
 from __future__ import annotations
@@ -40,13 +37,15 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "cached_gather",
     "cached_gather_blocks",
-    "cached_gather_select",
     "default_interpret",
-    "dma_supported",
+    "lane_layout",
 ]
 
 LANE = 128
 ROW_BLOCK = 8  # default rows per DMA tile in the row-block variant
+# Rows per grid step.  A 1-D int32 operand is laid out in 1024-entry tiles,
+# so a scalar-memory block of a longer index array must be a multiple of it.
+TILE_ROWS = 1024
 
 
 def default_interpret() -> bool:
@@ -54,561 +53,312 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-# --------------------------------------------------------- double buffered
+def lane_layout(table: jax.Array) -> jax.Array:
+    """``[R, F]`` → ``[R * k, 128]``: the feature axis zero-padded to
+    ``k = ceil(F / 128)`` lane groups, one table row per ``k`` lane rows."""
+    r, f = table.shape
+    k = -(-f // LANE)
+    if f != k * LANE:
+        table = jnp.pad(table, ((0, 0), (0, k * LANE - f)))
+    return table.reshape(r * k, LANE)
 
 
-def _db_kernel(
-    idx_ref,
-    pos_raw_ref,
-    pos_clamped_ref,
-    hot_hbm,
-    host_hbm,
-    out_hbm,
-    scratch,
-    in_sems,
-    out_sems,
-    *,
-    n_rows: int,
-    block_f: int,
-    n_buffers: int,
+def _tiling(s: int, unit: int) -> tuple[int, int]:
+    """(rows per grid step, padded row count) for ``s`` rows in ``unit``s."""
+    sp = -(-s // unit) * unit
+    if sp <= TILE_ROWS:
+        return sp, sp
+    tile = TILE_ROWS - TILE_ROWS % unit
+    return tile, -(-s // tile) * tile
+
+
+def _windowed(n: int, window: int, start, wait) -> None:
+    """Issue ``n`` copies in order, keeping up to ``window`` in flight."""
+    if window > 1:
+        def prime(i, c):
+            start(i)
+            return c
+
+        jax.lax.fori_loop(0, min(window - 1, n), prime, 0)
+
+    def body(i, c):
+        @pl.when(i + window - 1 < n)
+        def _():
+            start(i + window - 1)
+
+        wait(i)
+        return c
+
+    jax.lax.fori_loop(0, n, body, 0)
+
+
+def _row_copies(idx_ref, pos_ref, hot_hbm, host_hbm, out_ref, sem, k):
+    """(start, wait) for the one-row copy of tile row ``r``.  All copies
+    share one semaphore and have one size, so a wait may be rebuilt from
+    any same-sized descriptor."""
+
+    def start(r):
+        p = pos_ref[r]
+        dst = out_ref.at[pl.ds(r * k, k)]
+
+        @pl.when(p >= 0)
+        def _():
+            pltpu.make_async_copy(hot_hbm.at[pl.ds(p * k, k)], dst, sem).start()
+
+        @pl.when(p < 0)
+        def _():
+            pltpu.make_async_copy(host_hbm.at[pl.ds(idx_ref[r] * k, k)], dst, sem).start()
+
+    def wait(r):
+        pltpu.make_async_copy(host_hbm.at[pl.ds(0, k)], out_ref.at[pl.ds(r * k, k)], sem).wait()
+
+    return start, wait
+
+
+def _row_kernel(idx_ref, pos_ref, hot_hbm, host_hbm, out_ref, sem, *, k: int, window: int):
+    start, wait = _row_copies(idx_ref, pos_ref, hot_hbm, host_hbm, out_ref, sem, k)
+    _windowed(idx_ref.shape[0], window, start, wait)
+
+
+def _block_kernel(
+    idx_ref, pos_ref, hot_hbm, host_hbm, out_ref, sem, mode_ref, *, k, row_block, window
 ):
-    j = pl.program_id(0)
-    col = pl.ds(j * block_f, block_f)
-
-    # The DMA descriptor is rebuilt identically at start and wait time (the
-    # semaphore carries the in-flight state); the hit test picks the source
-    # table, so only the winning row is ever copied.
-    def in_copy(slot, i, op):
-        hit = pos_raw_ref[i] >= 0
-
-        @pl.when(hit)
-        def _():
-            op(
-                pltpu.make_async_copy(
-                    hot_hbm.at[pos_clamped_ref[i], col], scratch.at[slot], in_sems.at[slot]
-                )
-            )
-
-        @pl.when(~hit)
-        def _():
-            op(
-                pltpu.make_async_copy(
-                    host_hbm.at[idx_ref[i], col], scratch.at[slot], in_sems.at[slot]
-                )
-            )
-
-    def out_copy(slot, i):
-        return pltpu.make_async_copy(scratch.at[slot], out_hbm.at[i, col], out_sems.at[slot])
-
-    if n_buffers == 1:  # serial ablation: copy, wait, write back, wait
-        def serial_body(i, _):
-            in_copy(0, i, lambda dma: dma.start())
-            in_copy(0, i, lambda dma: dma.wait())
-            dma = out_copy(0, i)
-            dma.start()
-            dma.wait()
-            return 0
-
-        jax.lax.fori_loop(0, n_rows, serial_body, 0)
-        return
-
-    in_copy(0, 0, lambda dma: dma.start())
-
-    def body(i, _):
-        slot = jax.lax.rem(i, n_buffers)
-        nxt = jax.lax.rem(i + 1, n_buffers)
-
-        @pl.when(i + 1 < n_rows)
-        def _():
-            # Reusing a slot: its previous write-back must have drained
-            # before the incoming copy may overwrite the tile.
-            @pl.when(i + 1 >= n_buffers)
-            def _():
-                out_copy(nxt, i + 1 - n_buffers).wait()
-
-            in_copy(nxt, i + 1, lambda dma: dma.start())
-
-        in_copy(slot, i, lambda dma: dma.wait())
-        out_copy(slot, i).start()
-        return 0
-
-    jax.lax.fori_loop(0, n_rows, body, 0)
-
-    tail = jnp.minimum(n_rows, n_buffers)
-
-    def drain(k, _):
-        i = n_rows - tail + k
-
-        @pl.when(i < n_rows)
-        def _():
-            out_copy(jax.lax.rem(i, n_buffers), i).wait()
-
-        return 0
-
-    jax.lax.fori_loop(0, tail, drain, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("block_f", "gather_buffers", "interpret"))
-def _cached_gather_db(
-    hot_table: jax.Array,
-    host_table: jax.Array,
-    indices: jax.Array,
-    positions: jax.Array,
-    *,
-    block_f: int,
-    gather_buffers: int,
-    interpret: bool,
-) -> jax.Array:
-    s = indices.shape[0]
-    f = host_table.shape[1]
-    block_f = min(block_f, f)
-    if f % block_f != 0:
-        pad = block_f - f % block_f
-        hot_table = jnp.pad(hot_table, ((0, 0), (0, pad)))
-        host_table = jnp.pad(host_table, ((0, 0), (0, pad)))
-    fp = host_table.shape[1]
-
-    idx = jnp.clip(indices.astype(jnp.int32), 0, host_table.shape[0] - 1)
-    pos_raw = positions.astype(jnp.int32)
-    pos_clamped = jnp.clip(pos_raw, 0, hot_table.shape[0] - 1)
-
-    out = pl.pallas_call(
-        functools.partial(_db_kernel, n_rows=s, block_f=block_f, n_buffers=gather_buffers),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(fp // block_f,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # hot table stays in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),  # host table stays in HBM
-            ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),  # the batch buffer
-            scratch_shapes=[
-                pltpu.VMEM((gather_buffers, block_f), host_table.dtype),
-                pltpu.SemaphoreType.DMA((gather_buffers,)),
-                pltpu.SemaphoreType.DMA((gather_buffers,)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((s, fp), host_table.dtype),
-        interpret=interpret,
-    )(idx, pos_raw, pos_clamped, hot_table, host_table)
-    return out[:, :f]
-
-
-# ---------------------------------------------------------- row-block tiles
-
-
-def _blk_kernel(
-    idx_ref,
-    pos_raw_ref,
-    pos_clamped_ref,
-    blk_mode_ref,
-    blk_start_ref,
-    hot_hbm,
-    host_hbm,
-    out_hbm,
-    scratch,
-    in_sems,
-    out_sems,
-    *,
-    n_blocks: int,
-    row_block: int,
-    block_f: int,
-    n_buffers: int,
-):
-    """Row-block variant of :func:`_db_kernel` (same rotation, coarser tiles).
+    """Row-block variant of :func:`_row_kernel` (same window, coarser copies).
 
     Sorted unique frontiers make whole row blocks land on *consecutive*
     source rows (hit runs are consecutive hot-table slots because slots are
     assigned in node-id order; miss runs are consecutive prefetch-pack
-    slots or dense id ranges).  Per block, the prefetched ``blk_mode``
-    says how it was classified host-side: 1 = contiguous hit run → ONE
-    HBM→VMEM DMA for all ``row_block`` rows from the hot table, 2 =
-    contiguous miss run → one DMA from the host table, 0 = mixed/broken →
-    per-row copies into the block's scratch tile (the original
-    one-descriptor-per-row schedule, confined to blocks that need it).
-    Write-back is always one VMEM→HBM DMA per block — output rows are
-    consecutive by construction.  The ``gather_buffers`` slots rotate at
-    block granularity.
+    slots or dense id ranges).  Each block is classified from the scalar
+    operands when its copy starts, and the class is kept in ``mode_ref``
+    for the wait: 1 = contiguous hit run → ONE copy of ``row_block`` rows
+    from the hot table, 2 = contiguous miss run → one copy from the host
+    table, 0 = mixed/broken (a pad row breaks a run too) → per-row copies.
     """
-    j = pl.program_id(0)
-    col = pl.ds(j * block_f, block_f)
+    row_start, row_wait = _row_copies(idx_ref, pos_ref, hot_hbm, host_hbm, out_ref, sem, k)
+    run = row_block * k
 
-    def in_copy(slot, b, op):
-        mode = blk_mode_ref[b]
+    def source(r):
+        p = pos_ref[r]
+        return p >= 0, jnp.where(p >= 0, p, idx_ref[r])
+
+    def classify(r0):
+        hit0, src0 = source(r0)
+
+        def same_run(j, ok):
+            hit, src = source(r0 + j)
+            return ok & (hit == hit0).astype(jnp.int32) & (src == src0 + j).astype(jnp.int32)
+
+        contig = jax.lax.fori_loop(1, row_block, same_run, jnp.int32(1))
+        return jnp.where(contig == 1, jnp.where(hit0, 1, 2), 0).astype(jnp.int32), src0
+
+    def start(b):
+        r0 = b * row_block
+        mode, src0 = classify(r0)
+        mode_ref[b] = mode
+        dst = out_ref.at[pl.ds(r0 * k, run)]
 
         @pl.when(mode == 1)
         def _():
-            op(
-                pltpu.make_async_copy(
-                    hot_hbm.at[pl.ds(blk_start_ref[b], row_block), col],
-                    scratch.at[slot],
-                    in_sems.at[slot],
-                )
-            )
+            pltpu.make_async_copy(hot_hbm.at[pl.ds(src0 * k, run)], dst, sem).start()
 
         @pl.when(mode == 2)
         def _():
-            op(
-                pltpu.make_async_copy(
-                    host_hbm.at[pl.ds(blk_start_ref[b], row_block), col],
-                    scratch.at[slot],
-                    in_sems.at[slot],
-                )
-            )
+            pltpu.make_async_copy(host_hbm.at[pl.ds(src0 * k, run)], dst, sem).start()
 
         @pl.when(mode == 0)
         def _():
-            # Broken run: per-row winning-source copies into the block
-            # tile.  Starts and waits rebuild identical descriptors on the
-            # block's one semaphore, so the wait pass drains exactly the
-            # copies the start pass issued.
-            def row(r, _):
-                i = b * row_block + r
-                hit = pos_raw_ref[i] >= 0
-
-                @pl.when(hit)
-                def _():
-                    op(
-                        pltpu.make_async_copy(
-                            hot_hbm.at[pos_clamped_ref[i], col],
-                            scratch.at[slot, r],
-                            in_sems.at[slot],
-                        )
-                    )
-
-                @pl.when(~hit)
-                def _():
-                    op(
-                        pltpu.make_async_copy(
-                            host_hbm.at[idx_ref[i], col],
-                            scratch.at[slot, r],
-                            in_sems.at[slot],
-                        )
-                    )
-
-                return 0
+            def row(j, c):
+                row_start(r0 + j)
+                return c
 
             jax.lax.fori_loop(0, row_block, row, 0)
 
-    def out_copy(slot, b):
-        return pltpu.make_async_copy(
-            scratch.at[slot], out_hbm.at[pl.ds(b * row_block, row_block), col], out_sems.at[slot]
-        )
+    def wait(b):
+        r0 = b * row_block
 
-    if n_buffers == 1:  # serial ablation at block granularity
-        def serial_body(b, _):
-            in_copy(0, b, lambda dma: dma.start())
-            in_copy(0, b, lambda dma: dma.wait())
-            dma = out_copy(0, b)
-            dma.start()
-            dma.wait()
-            return 0
-
-        jax.lax.fori_loop(0, n_blocks, serial_body, 0)
-        return
-
-    in_copy(0, 0, lambda dma: dma.start())
-
-    def body(b, _):
-        slot = jax.lax.rem(b, n_buffers)
-        nxt = jax.lax.rem(b + 1, n_buffers)
-
-        @pl.when(b + 1 < n_blocks)
+        @pl.when(mode_ref[b] != 0)
         def _():
-            @pl.when(b + 1 >= n_buffers)
-            def _():
-                out_copy(nxt, b + 1 - n_buffers).wait()
+            pltpu.make_async_copy(
+                host_hbm.at[pl.ds(0, run)], out_ref.at[pl.ds(r0 * k, run)], sem
+            ).wait()
 
-            in_copy(nxt, b + 1, lambda dma: dma.start())
-
-        in_copy(slot, b, lambda dma: dma.wait())
-        out_copy(slot, b).start()
-        return 0
-
-    jax.lax.fori_loop(0, n_blocks, body, 0)
-
-    tail = jnp.minimum(n_blocks, n_buffers)
-
-    def drain(k, _):
-        b = n_blocks - tail + k
-
-        @pl.when(b < n_blocks)
+        @pl.when(mode_ref[b] == 0)
         def _():
-            out_copy(jax.lax.rem(b, n_buffers), b).wait()
+            def row(j, c):
+                row_wait(r0 + j)
+                return c
 
-        return 0
+            jax.lax.fori_loop(0, row_block, row, 0)
 
-    jax.lax.fori_loop(0, tail, drain, 0)
+    _windowed(idx_ref.shape[0] // row_block, window, start, wait)
+
+
+def _gather_call(kernel, idx, pos, hot, host, *, s, sp, tile, k, feat_dim, interpret, scratch=()):
+    """Run ``kernel`` over ``sp // tile`` row tiles, the per-row int32
+    operands blocked into scalar memory one tile at a time."""
+    smem = pl.BlockSpec((tile,), lambda t: (t,), memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid=(sp // tile,),
+        in_specs=[
+            smem,
+            smem,
+            pl.BlockSpec(memory_space=pl.ANY),  # hot table stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # host table stays in HBM
+        ],
+        out_specs=pl.BlockSpec((tile * k, LANE), lambda t: (t, 0)),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(()), *scratch],
+        out_shape=jax.ShapeDtypeStruct((sp * k, LANE), host.dtype),
+        interpret=interpret,
+    )(idx, pos, hot, host)
+    return out.reshape(sp, k * LANE)[:s, :feat_dim]
+
+
+def _operands(indices, positions, sp, *, hot_rows, host_rows):
+    """Clamp and pad the per-row operands; pad rows are misses of host row 0."""
+    s = indices.shape[0]
+    idx = jnp.clip(indices.astype(jnp.int32), 0, host_rows - 1)
+    pos = positions.astype(jnp.int32)
+    pos = jnp.where(pos >= 0, jnp.minimum(pos, hot_rows - 1), -1)
+    if sp != s:
+        idx = jnp.pad(idx, (0, sp - s))
+        pos = jnp.pad(pos, (0, sp - s), constant_values=-1)
+    return idx, pos
+
+
+def _pad_rows(table, rows):
+    """At least ``rows`` lane rows: a run copy's source slice has a static
+    size, and interpret mode traces both sides of every branch."""
+    return table if table.shape[0] >= rows else jnp.pad(table, ((0, rows - table.shape[0]), (0, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("feat_dim", "gather_buffers", "interpret"))
+def _cached_gather_rows(hot, host, indices, positions, *, feat_dim, gather_buffers, interpret):
+    k = -(-feat_dim // LANE)
+    s = indices.shape[0]
+    tile, sp = _tiling(s, 1)
+    idx, pos = _operands(
+        indices, positions, sp, hot_rows=hot.shape[0] // k, host_rows=host.shape[0] // k
+    )
+    return _gather_call(
+        functools.partial(_row_kernel, k=k, window=gather_buffers),
+        idx,
+        pos,
+        hot,
+        host,
+        s=s,
+        sp=sp,
+        tile=tile,
+        k=k,
+        feat_dim=feat_dim,
+        interpret=interpret,
+    )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("row_block", "block_f", "gather_buffers", "interpret")
+    jax.jit, static_argnames=("feat_dim", "row_block", "gather_buffers", "interpret")
 )
 def _cached_gather_blocks(
+    hot, host, indices, positions, *, feat_dim, row_block, gather_buffers, interpret
+):
+    k = -(-feat_dim // LANE)
+    s = indices.shape[0]
+    tile, sp = _tiling(s, row_block)
+    idx, pos = _operands(
+        indices, positions, sp, hot_rows=hot.shape[0] // k, host_rows=host.shape[0] // k
+    )
+    hot = _pad_rows(hot, row_block * k)
+    host = _pad_rows(host, row_block * k)
+    return _gather_call(
+        functools.partial(_block_kernel, k=k, row_block=row_block, window=gather_buffers),
+        idx,
+        pos,
+        hot,
+        host,
+        s=s,
+        sp=sp,
+        tile=tile,
+        k=k,
+        feat_dim=feat_dim,
+        interpret=interpret,
+        scratch=(pltpu.SMEM((tile // row_block,), jnp.int32),),
+    )
+
+
+def _check(hot_table, host_table, gather_buffers, feat_dim):
+    if hot_table.shape[1] != host_table.shape[1]:
+        raise ValueError("hot and host tables must share the feature dim")
+    if gather_buffers < 1:
+        raise ValueError(f"gather_buffers must be >= 1, got {gather_buffers}")
+    if feat_dim is None:
+        return lane_layout(hot_table), lane_layout(host_table), host_table.shape[1]
+    if host_table.shape[1] != LANE:
+        raise ValueError(f"lane-layout tables must be {LANE} wide, got {host_table.shape[1]}")
+    return hot_table, host_table, feat_dim
+
+
+def cached_gather(
+    hot_table: jax.Array,  # [H, F], or [H * k, 128] lane layout with feat_dim
+    host_table: jax.Array,  # [N, F], or [N * k, 128] lane layout with feat_dim
+    indices: jax.Array,  # int32 [S]
+    positions: jax.Array,  # int32 [S] (slot or -1)
+    *,
+    feat_dim: int | None = None,
+    gather_buffers: int = 2,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Two-source gather; see the module docstring.  Returns ``[S, F]``.
+
+    ``feat_dim=None`` takes ``[R, F]`` tables and builds their lane layout
+    on every call; callers that gather repeatedly pass tables already in
+    :func:`lane_layout` together with the logical ``feat_dim``.
+    ``gather_buffers`` is the number of row copies kept in flight (1 =
+    serial copies, 2 = double buffering, the default).
+    """
+    hot, host, feat_dim = _check(hot_table, host_table, gather_buffers, feat_dim)
+    if interpret is None:
+        interpret = default_interpret()
+    if indices.shape[0] == 0:  # nothing to gather; skip the kernel launch
+        return jnp.zeros((0, feat_dim), host_table.dtype)
+    return _cached_gather_rows(
+        hot,
+        host,
+        indices,
+        positions,
+        feat_dim=feat_dim,
+        gather_buffers=gather_buffers,
+        interpret=interpret,
+    )
+
+
+def cached_gather_blocks(
     hot_table: jax.Array,
     host_table: jax.Array,
     indices: jax.Array,
     positions: jax.Array,
     *,
-    row_block: int,
-    block_f: int,
-    gather_buffers: int,
-    interpret: bool,
-) -> jax.Array:
-    s = indices.shape[0]
-    f = host_table.shape[1]
-    block_f = min(block_f, f)
-    if f % block_f != 0:
-        pad = block_f - f % block_f
-        hot_table = jnp.pad(hot_table, ((0, 0), (0, pad)))
-        host_table = jnp.pad(host_table, ((0, 0), (0, pad)))
-    fp = host_table.shape[1]
-
-    # Pad the row axis to whole blocks; pad rows are misses of host row 0,
-    # gathered into the padded output tail and sliced off.  A pad inside
-    # the last block just breaks that block's run (mode 0).
-    sp = -(-s // row_block) * row_block
-    idx = jnp.clip(indices.astype(jnp.int32), 0, host_table.shape[0] - 1)
-    # Both source tables must hold at least one whole row block: the
-    # run-DMA slice has a static [row_block, block_f] size, so tracing it
-    # (interpret mode evaluates both sides of every pl.when) requires the
-    # operand to be that tall even when no run could classify.  Classified
-    # runs are in range by construction, so the pad rows are never read.
-    if hot_table.shape[0] < row_block:
-        hot_table = jnp.pad(hot_table, ((0, row_block - hot_table.shape[0]), (0, 0)))
-    if host_table.shape[0] < row_block:
-        host_table = jnp.pad(host_table, ((0, row_block - host_table.shape[0]), (0, 0)))
-    pos_raw = positions.astype(jnp.int32)
-    if sp != s:
-        idx = jnp.pad(idx, (0, sp - s))
-        pos_raw = jnp.pad(pos_raw, (0, sp - s), constant_values=-1)
-    pos_clamped = jnp.clip(pos_raw, 0, hot_table.shape[0] - 1)
-    n_blocks = sp // row_block
-
-    # Host-side (well, jnp-side — still on device, still prefetched as
-    # scalars) run classification: a block is one DMA when all its rows
-    # read the same source at consecutive row indices.
-    hit = pos_raw >= 0
-    src = jnp.where(hit, pos_clamped, idx).reshape(n_blocks, row_block)
-    hit_b = hit.reshape(n_blocks, row_block)
-    if row_block > 1:
-        contig = jnp.all(src[:, 1:] == src[:, :-1] + 1, axis=1)
-    else:
-        contig = jnp.ones((n_blocks,), bool)
-    all_hit = jnp.all(hit_b, axis=1)
-    all_miss = jnp.all(~hit_b, axis=1)
-    blk_mode = jnp.where(
-        contig & all_hit, 1, jnp.where(contig & all_miss, 2, 0)
-    ).astype(jnp.int32)
-    # Contiguous runs must fit the source table: the run reads rows
-    # [start, start+row_block), and every row of a classified run is an
-    # in-range per-row index, so the run itself is in range by
-    # construction — blk_start is only read for modes 1/2.
-    blk_start = src[:, 0]
-
-    out = pl.pallas_call(
-        functools.partial(
-            _blk_kernel,
-            n_blocks=n_blocks,
-            row_block=row_block,
-            block_f=block_f,
-            n_buffers=gather_buffers,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(fp // block_f,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),  # hot table stays in HBM
-                pl.BlockSpec(memory_space=pltpu.ANY),  # host table stays in HBM
-            ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-            scratch_shapes=[
-                pltpu.VMEM((gather_buffers, row_block, block_f), host_table.dtype),
-                pltpu.SemaphoreType.DMA((gather_buffers,)),
-                pltpu.SemaphoreType.DMA((gather_buffers,)),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((sp, fp), host_table.dtype),
-        interpret=interpret,
-    )(idx, pos_raw, pos_clamped, blk_mode, blk_start, hot_table, host_table)
-    return out[:s, :f]
-
-
-def cached_gather_blocks(
-    hot_table: jax.Array,  # [H, F]
-    host_table: jax.Array,  # [N, F]
-    indices: jax.Array,  # int32 [S]
-    positions: jax.Array,  # int32 [S] (slot or -1)
-    *,
+    feat_dim: int | None = None,
     row_block: int = ROW_BLOCK,
-    block_f: int = 512,
     gather_buffers: int = 2,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Row-block two-source gather for sorted-run frontiers.
 
-    Semantics are identical to :func:`cached_gather` for ANY index order —
-    blocks that are not contiguous single-source runs fall back to per-row
-    copies inside the kernel — but the intended caller hands it a deduped
-    (sorted unique) frontier, where most blocks collapse to one DMA
-    descriptor per ``row_block`` rows.  Falls back to :func:`cached_gather`
-    where interpret-mode DMA is unavailable or ``row_block == 1``.
+    Semantics and table arguments are those of :func:`cached_gather` for
+    ANY index order — blocks that are not contiguous single-source runs
+    fall back to per-row copies inside the kernel — but the intended
+    caller hands it a deduped (sorted unique) frontier, where most blocks
+    collapse to one copy per ``row_block`` rows.  ``row_block == 1`` is
+    :func:`cached_gather`.
     """
-    if hot_table.shape[1] != host_table.shape[1]:
-        raise ValueError("hot and host tables must share the feature dim")
-    if gather_buffers < 1:
-        raise ValueError(f"gather_buffers must be >= 1, got {gather_buffers}")
     if row_block < 1:
         raise ValueError(f"row_block must be >= 1, got {row_block}")
+    hot, host, feat_dim = _check(hot_table, host_table, gather_buffers, feat_dim)
     if interpret is None:
         interpret = default_interpret()
     if indices.shape[0] == 0:
-        return jnp.zeros((0, host_table.shape[1]), host_table.dtype)
-    if row_block == 1 or not dma_supported():
-        return cached_gather(
-            hot_table,
-            host_table,
-            indices,
-            positions,
-            block_f=block_f,
-            gather_buffers=gather_buffers,
-            interpret=interpret,
-        )
-    return _cached_gather_blocks(
-        hot_table,
-        host_table,
-        indices,
-        positions,
-        row_block=row_block,
-        block_f=block_f,
-        gather_buffers=gather_buffers,
-        interpret=interpret,
-    )
-
-
-# ------------------------------------------------- select-based (fallback)
-
-
-def _select_kernel(idx_ref, pos_raw_ref, pos_clamped_ref, hot_ref, host_ref, out_ref):
-    del idx_ref, pos_clamped_ref
-    i = pl.program_id(0)
-    hit = pos_raw_ref[i] >= 0
-    out_ref[...] = jnp.where(hit, hot_ref[...], host_ref[...])
-
-
-@functools.partial(jax.jit, static_argnames=("block_f", "interpret"))
-def cached_gather_select(
-    hot_table: jax.Array,  # [H, F]
-    host_table: jax.Array,  # [N, F]
-    indices: jax.Array,  # int32 [S]
-    positions: jax.Array,  # int32 [S] (slot or -1)
-    *,
-    block_f: int = 512,
-    interpret: bool = True,
-) -> jax.Array:
-    """Single-buffered variant: BlockSpec index_maps stage BOTH candidate
-    tiles per row and the body selects between them — twice the DMA traffic
-    of the double-buffered kernel, but it needs no DMA primitives, so it is
-    the fallback on JAX versions whose interpret mode lacks them."""
-    if hot_table.shape[1] != host_table.shape[1]:
-        raise ValueError("hot and host tables must share the feature dim")
-    s = indices.shape[0]
-    f = host_table.shape[1]
-    block_f = min(block_f, f)
-    if f % block_f != 0:
-        pad = block_f - f % block_f
-        hot_table = jnp.pad(hot_table, ((0, 0), (0, pad)))
-        host_table = jnp.pad(host_table, ((0, 0), (0, pad)))
-    fp = host_table.shape[1]
-
-    idx = jnp.clip(indices.astype(jnp.int32), 0, host_table.shape[0] - 1)
-    pos_raw = positions.astype(jnp.int32)
-    pos_clamped = jnp.clip(pos_raw, 0, hot_table.shape[0] - 1)
-
-    grid = (s, fp // block_f)
-    out = pl.pallas_call(
-        _select_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                # hot tile: row picked by the prefetched (clamped) cache slot
-                pl.BlockSpec((1, block_f), lambda i, j, idx, praw, pcl: (pcl[i], j)),
-                # host tile: row picked by the prefetched node id
-                pl.BlockSpec((1, block_f), lambda i, j, idx, praw, pcl: (idx[i], j)),
-            ],
-            out_specs=pl.BlockSpec((1, block_f), lambda i, j, idx, praw, pcl: (i, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((s, fp), host_table.dtype),
-        interpret=interpret,
-    )(idx, pos_raw, pos_clamped, hot_table, host_table)
-    return out[:, :f]
-
-
-# ------------------------------------------------------------- public entry
-
-_DMA_PROBE: bool | None = None
-
-
-def dma_supported() -> bool:
-    """Once per process: can this backend/JAX run the manual-DMA kernel?
-
-    TPU always can; in interpret mode older JAX releases lack DMA
-    semantics, so a tiny probe call decides (and its failure is the
-    fallback signal, not an error)."""
-    global _DMA_PROBE
-    if jax.default_backend() == "tpu":
-        return True
-    if _DMA_PROBE is None:
-        try:
-            hot = jnp.zeros((1, LANE), jnp.float32)
-            host = jnp.ones((2, LANE), jnp.float32)
-            idx = jnp.zeros((2,), jnp.int32)
-            pos = jnp.array([-1, 0], jnp.int32)
-            out = _cached_gather_db(
-                hot, host, idx, pos, block_f=LANE, gather_buffers=2, interpret=True
-            )
-            _DMA_PROBE = bool(out[0, 0] == 1.0 and out[1, 0] == 0.0)
-        except Exception:  # pragma: no cover - old-JAX interpret mode
-            _DMA_PROBE = False
-    return _DMA_PROBE
-
-
-def cached_gather(
-    hot_table: jax.Array,  # [H, F]
-    host_table: jax.Array,  # [N, F]
-    indices: jax.Array,  # int32 [S]
-    positions: jax.Array,  # int32 [S] (slot or -1)
-    *,
-    block_f: int = 512,
-    gather_buffers: int = 2,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """Double-buffered two-source gather; see the module docstring.
-
-    ``interpret=None`` resolves by backend (compiled on TPU, interpret
-    elsewhere); ``gather_buffers`` is the number of VMEM row-tile slots
-    (1 = serial copies, 2 = double buffering, the default).  Falls back to
-    :func:`cached_gather_select` where interpret-mode DMA is unavailable.
-    """
-    if hot_table.shape[1] != host_table.shape[1]:
-        raise ValueError("hot and host tables must share the feature dim")
-    if gather_buffers < 1:
-        raise ValueError(f"gather_buffers must be >= 1, got {gather_buffers}")
-    if interpret is None:
-        interpret = default_interpret()
-    if indices.shape[0] == 0:  # nothing to gather; skip the kernel launch
-        return jnp.zeros((0, host_table.shape[1]), host_table.dtype)
-    if not dma_supported():
-        return cached_gather_select(
-            hot_table, host_table, indices, positions, block_f=block_f, interpret=interpret
-        )
-    return _cached_gather_db(
-        hot_table,
-        host_table,
-        indices,
-        positions,
-        block_f=block_f,
-        gather_buffers=gather_buffers,
-        interpret=interpret,
-    )
+        return jnp.zeros((0, feat_dim), host_table.dtype)
+    kw = dict(feat_dim=feat_dim, gather_buffers=gather_buffers, interpret=interpret)
+    if row_block == 1:
+        return _cached_gather_rows(hot, host, indices, positions, **kw)
+    return _cached_gather_blocks(hot, host, indices, positions, row_block=row_block, **kw)

@@ -1,11 +1,10 @@
 """Public op: cached feature gather (kernel on TPU, oracle elsewhere).
 
-``use_kernel=True`` routes through the double-buffered Pallas kernel —
-compiled when the backend is TPU, interpret mode elsewhere (the default is
-resolved per backend by :func:`~repro.kernels.cached_gather.kernel.default_interpret`,
-no longer hardcoded).  The kernel DMAs only the winning source tile per
-row (hit → hot cache, miss → host table) and overlaps row ``i+1``'s copy
-with row ``i``'s write-back via ``gather_buffers`` VMEM slots.
+``use_kernel=True`` routes through the Pallas kernel — compiled when the
+backend is TPU, interpret mode on the CPU (resolved per backend by
+:func:`~repro.kernels.cached_gather.kernel.default_interpret`).  The kernel
+copies only the winning source row (hit → hot cache, miss → host table),
+keeping ``gather_buffers`` row copies in flight.
 """
 
 from __future__ import annotations
@@ -39,10 +38,10 @@ def cached_feature_gather(
         for a cache miss (the ``FeatureStore.position_map`` lookup).
       use_kernel: route through the Pallas kernel instead of the jnp
         oracle.
-      gather_buffers: VMEM row-tile slots in the kernel (1 = serial
+      gather_buffers: row copies the kernel keeps in flight (1 = serial
         copies, 2 = double buffering).
       interpret: force interpret mode on/off; ``None`` resolves by backend
-        (compiled on TPU, interpret elsewhere).
+        (compiled on TPU, interpret mode on the CPU).
 
     Returns:
       ``f32[S, F]`` — row ``i`` is ``hot_table[positions[i]]`` on a hit,

@@ -32,6 +32,7 @@ from repro.runtime.request_queue import (
     poisson_trace,
     uniform_seed_batches,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _depth(value: str):
@@ -117,14 +118,14 @@ def main() -> None:
     ap.add_argument(
         "--use-kernel",
         action="store_true",
-        help="route feature gathers through the double-buffered Pallas "
-        "cached_gather kernel (compiled on TPU, interpret mode elsewhere)",
+        help="route feature gathers through the Pallas cached_gather kernel "
+        "(compiled on a TPU, interpret mode on the CPU)",
     )
     ap.add_argument(
         "--gather-buffers",
         type=int,
         default=2,
-        help="kernel VMEM row-tile slots: 1 = serial copies, 2 = double "
+        help="kernel row copies kept in flight: 1 = serial copies, 2 = double "
         "buffering (only meaningful with --use-kernel)",
     )
     ap.add_argument(
@@ -269,6 +270,7 @@ def main() -> None:
         "under the 'metrics' key",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace_jax and args.trace is None:
         ap.error("--trace-jax requires --trace")
@@ -294,7 +296,7 @@ def main() -> None:
     # One typed config object carries every execution knob from here down —
     # the engine, the servers, and the report echoes all read it.
     cfg = ServeConfig.from_args(args)
-    ds = load_dataset(args.dataset, scale=args.scale, max_nodes=200_000)
+    ds = load_dataset(args.dataset, scale=args.scale)
     eng = GNNInferenceEngine(
         ds,
         model=args.model,

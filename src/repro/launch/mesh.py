@@ -32,9 +32,9 @@ def make_serving_mesh(num_shards: int):
     Clamps to the devices actually present, so ``make_serving_mesh(4)``
     on a 1-device host returns a size-1 mesh (the sharded server then
     co-locates its shards — same partition math, same accounting, no
-    cross-device traffic).  Built directly from the device array rather
-    than ``jax.make_mesh`` so the oldest supported jax still constructs
-    it."""
+    cross-device traffic).  A caller that needs the full size checks
+    ``mesh.size``.  Built from the device array, not ``jax.make_mesh``,
+    so the mesh takes exactly the first ``num_shards`` devices."""
     if num_shards < 1:
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")
     devices = jax.devices()[: max(1, min(num_shards, len(jax.devices())))]

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.lm.config import LMConfig
-from repro.utils.jax_compat import shard_map_compat
 
 __all__ = [
     "init_moe_params",
@@ -248,7 +247,7 @@ def _moe_ffn_shard_map(
             aux = jax.lax.pmean(aux, data_axes)
         return out.reshape(b_l, s, d), aux
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(param_specs, dspec),

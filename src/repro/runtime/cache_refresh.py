@@ -56,7 +56,9 @@ import numpy as np
 
 from repro.core.allocation import reallocate_capacity
 from repro.core.cache import CacheRefreshDelta
+from repro.core.faults import InjectedFault
 from repro.core.presample import run_presampling
+from repro.core.retry import RetryExhausted, StageTimeout
 from repro.core.telemetry import WorkloadTelemetry, merge_windows
 from repro.core.trace import NULL_TRACER
 from repro.graph.csc import BYTES_PER_ADJ_ELEMENT
@@ -496,9 +498,11 @@ class CacheRefreshManager:
                 edge_counts=self._edge_counts,
                 injector=self.injector,
             )
-        except Exception as err:
+        except (InjectedFault, RetryExhausted, StageTimeout) as err:
             # DualCache.refresh already rolled its state back; record the
             # failure and keep serving the stale epoch (see RefreshFailure).
+            # Only fault-subsystem errors are absorbed: a real error in the
+            # re-fill (device OOM, compile error, bug) propagates.
             failure = RefreshFailure(
                 reason=reason,
                 error=repr(err),

@@ -14,9 +14,9 @@ the timing semantics of Fig. 1/7), ``depth>1`` keeps that many batches in
 flight so batch *i+1*'s sampling/gather overlap batch *i*'s GNN forward.
 Four further execution knobs — ``prefetch`` (stage batch *i+1*'s missed
 host feature rows onto the device during batch *i*'s forward),
-``use_kernel`` (route gathers through the double-buffered Pallas
-``cached_gather`` kernel), ``gather_buffers`` (the kernel's VMEM slot
-count), and ``dedup`` (sort-and-unique each input frontier on device and
+``use_kernel`` (route gathers through the Pallas ``cached_gather``
+kernel), ``gather_buffers`` (the kernel's row copies in flight), and
+``dedup`` (sort-and-unique each input frontier on device and
 gather/prefetch/model one row per DISTINCT node, expanding through the
 inverse map) — default from the prepared pipeline.  Outputs, hit counts,
 and batch order are identical under every knob combination; only where
@@ -118,6 +118,9 @@ class InferenceReport:
     # MetricsRegistry.snapshot() at report time when the run was given a
     # registry (``--metrics``); None otherwise.
     metrics: dict | None = None
+    # Fault-tolerance outcomes (zero without an injector).
+    kernel_fallbacks: int = 0  # kernel_gather faults rerouted to the table path
+    degraded_batches: int = 0  # batches served cache-only (miss path down)
 
     @property
     def total_seconds(self) -> float:
@@ -908,6 +911,8 @@ class GNNInferenceEngine:
         )
         object.__setattr__(ghost, "_host_np", store.host_np())
         object.__setattr__(ghost, "_position_np", store.position_np())
+        if use_kernel:
+            object.__setattr__(ghost, "_host_lanes", store.kernel_tables()[1])
         wblock = sample_blocks(
             jax.random.PRNGKey(self.seed + 1), pipe.caches.dgraph, jnp.asarray(seeds),
             self.fanouts, dedup=dedup,
@@ -1194,6 +1199,8 @@ class GNNInferenceEngine:
             refresh_events=list(manager.events) if manager is not None else [],
             epoch_hits=rt.epoch_hit_rates() if manager is not None else None,
             config=resolved_cfg,
+            kernel_fallbacks=rt.kernel_fallbacks,
+            degraded_batches=rt.degraded_batches,
         )
         if metrics is not None:
             metrics.counter("batches_total", policy=pipe.name).inc(report.num_batches)

@@ -144,6 +144,7 @@ class StreamReport:
     requests_retried: int = 0
     requests_degraded: int = 0
     stage_retries: int = 0  # individual backoff retries across all sites
+    kernel_fallbacks: int = 0  # kernel_gather faults rerouted to the table path
 
     @property
     def adj_hit_rate(self) -> float:
@@ -214,6 +215,7 @@ class ServeReport:
     requests_timed_out: int = 0
     requests_retried: int = 0
     requests_degraded: int = 0
+    kernel_fallbacks: int = 0  # kernel_gather faults rerouted to the table path
     unserved: int = 0  # requests/batches still queued when the loop ended
     fault_policy: str = "fail"
     faults: dict | None = None  # FaultInjector.counts() at report time
@@ -351,6 +353,7 @@ class ServeReport:
             out["requests_timed_out"] = self.requests_timed_out
             out["requests_retried"] = self.requests_retried
             out["requests_degraded"] = self.requests_degraded
+            out["kernel_fallbacks"] = self.kernel_fallbacks
             out["requests_shed"] = self.requests_shed
             out["unserved"] = self.unserved
         if self.failovers:
@@ -937,6 +940,7 @@ class MultiStreamServer:
             requests_timed_out=sum(r.requests_timed_out for r in stream_reports),
             requests_retried=sum(r.requests_retried for r in stream_reports),
             requests_degraded=sum(r.requests_degraded for r in stream_reports),
+            kernel_fallbacks=sum(r.kernel_fallbacks for r in stream_reports),
             unserved=self._unserved(),
             fault_policy=self.fault_policy,
             faults=self.injector.counts() if self.injector is not None else None,
@@ -989,6 +993,7 @@ class MultiStreamServer:
             requests_retried=s.batches_retried,
             requests_degraded=s.batches_degraded,
             stage_retries=rt.stage_retries,
+            kernel_fallbacks=rt.kernel_fallbacks,
         )
 
 

@@ -450,6 +450,26 @@ def test_degraded_mode_serves_cache_only_when_miss_path_is_down(small_dataset):
     assert rep.feat_lookups > 0 and rep.feat_hits > 0
 
 
+def test_kernel_faults_fall_back_to_the_table_route(small_dataset):
+    """kernel_gather down: every gather reroutes to the table route, which
+    is bit-identical by the kernel-parity contract, so nothing is degraded
+    and the serve report counts each reroute in ``kernel_fallbacks``."""
+    eng = _shared_engine(small_dataset)
+    queues = _queues(small_dataset, n=1, batches=2)
+    cfg = ServeConfig(engine=EngineConfig(pipeline_depth=1, use_kernel=True))
+    _, rb, ob = _serve(eng, queues, cfg=cfg)
+    assert rb.kernel_fallbacks == 0
+    plan = FaultPlan(rules=(FaultRule("kernel_gather"),))  # always down
+    srv, rf, of = _serve(
+        eng, queues, cfg=cfg.replace(fault_policy="fail"), injector=FaultInjector(plan)
+    )
+    offered = sum(len(q) for q in queues)
+    assert rf.kernel_fallbacks == offered == srv.streams[0].runtime.kernel_fallbacks
+    assert rf.requests_degraded == 0 and rf.availability == 1.0
+    assert rf.summary()["kernel_fallbacks"] == offered
+    _assert_same_serve(rb, ob, rf, of)
+
+
 def test_prefetch_faults_skip_staging_without_degrading(small_dataset):
     """A dead prefetch stage is invisible: staging is optional by design,
     so the serve falls back to gather-time fetches bit-identically and no
@@ -542,6 +562,31 @@ def test_refresh_manager_records_rollback_and_serving_continues(small_dataset):
     for a_list, b_list in zip(ob, of):
         for a, b in zip(a_list, b_list):
             np.testing.assert_array_equal(a, b)
+
+
+def test_refresh_manager_propagates_real_errors(small_dataset, monkeypatch):
+    """Only fault-subsystem errors roll a refresh back: a real error in the
+    re-fill (a device OOM, a compile error, a bug) propagates instead of
+    leaving a run that serves the stale epoch and still exits 0."""
+    from repro.core.cache import DualCache
+    from repro.runtime.cache_refresh import CacheRefreshManager, RefreshConfig
+
+    eng = _shared_engine(small_dataset)
+    manager = CacheRefreshManager(
+        eng.pipeline,
+        small_dataset,
+        fanouts=eng.fanouts,
+        batch_size=eng.batch_size,
+        config=RefreshConfig(mode="interval", interval_batches=2),
+    )
+
+    def broken_refresh(self, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    monkeypatch.setattr(DualCache, "refresh", broken_refresh)
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        manager.refresh("manual")
+    assert manager.failures == []
 
 
 # ------------------------------------------------------------- shard failover

@@ -137,3 +137,57 @@ def test_param_specs_structure_and_divisibility(arch):
         for dim, axis in zip(leaf.shape, tuple(spec) + (None,) * leaf.ndim):
             if axis == "model":
                 assert dim % 16 == 0, f"{jax.tree_util.keystr(path)}: {dim} % 16 != 0"
+
+
+# ------------------------------------------------------- determinism, cache
+
+
+def _dataset_digest(hash_seed: str) -> str:
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import hashlib; from repro.graph.datasets import load_dataset; "
+        "ds = load_dataset('reddit', scale=0.001, seed=3); "
+        "print(hashlib.sha256(ds.graph.row_index.tobytes() + ds.features.tobytes()).hexdigest())"
+    )
+    env = dict(os.environ, PYTHONHASHSEED=hash_seed, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c", code],
+        env=env,
+        capture_output=True,
+        text=True,
+        timeout=120,
+        check=True,
+    )
+    return out.stdout.strip()
+
+
+def test_dataset_is_a_function_of_the_seed_across_processes():
+    """``load_dataset`` derives its generator from a stable digest of the
+    name, not from ``hash(name)`` (salted per process): two processes with
+    different hash salts build the same graph from the same seed."""
+    assert _dataset_digest("1") == _dataset_digest("2")
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The env var wins and nothing else is set; otherwise the cache sits
+    at one fixed directory inside the checkout."""
+    from repro.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    min_time = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        monkeypatch.setenv(compile_cache.CACHE_ENV, "/elsewhere/jax-cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/jax-cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.CACHE_ENV)
+        path = compile_cache.enable_compile_cache()
+        repo = compile_cache.DEFAULT_CACHE_DIR.parent
+        assert path == str(repo / ".jax_cache") and (repo / "src" / "repro").is_dir()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().splitlines()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_time)

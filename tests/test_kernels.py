@@ -8,9 +8,7 @@ import pytest
 from repro.kernels.cached_gather.kernel import (
     cached_gather,
     cached_gather_blocks,
-    cached_gather_select,
     default_interpret,
-    dma_supported,
 )
 from repro.kernels.cached_gather.ref import cached_gather_ref
 from repro.kernels.flash_attention.kernel import flash_attention_2d
@@ -156,23 +154,8 @@ def test_cached_gather_blocks_buffer_rotation(gather_buffers):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-def test_cached_gather_select_fallback_matches_ref():
-    """The select-based fallback (for JAX versions without interpret-mode
-    DMA) must stay parity-tested alongside the double-buffered kernel."""
-    hot = jnp.asarray(RNG.standard_normal((8, 160)), jnp.float32)
-    host = jnp.asarray(RNG.standard_normal((30, 160)), jnp.float32)
-    idx = jnp.asarray(RNG.integers(0, 30, 11), jnp.int32)
-    pos = jnp.asarray(RNG.integers(-1, 8, 11), jnp.int32)
-    out = cached_gather_select(hot, host, idx, pos, interpret=True)
-    ref = cached_gather_ref(hot, host, idx, pos)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
 def test_interpret_default_resolves_by_backend():
     assert default_interpret() == (jax.default_backend() != "tpu")
-    # On TPU the DMA path is always available; elsewhere the probe decides
-    # (and on this container's JAX the interpret-mode DMA path exists).
-    assert isinstance(dma_supported(), bool)
 
 
 @pytest.mark.skipif(
