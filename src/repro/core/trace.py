@@ -46,6 +46,7 @@ import bisect
 import itertools
 import json
 import math
+import threading
 import time
 from typing import Any, Callable, Iterable, Mapping
 
@@ -68,7 +69,9 @@ class _Span:
     Timestamps are taken inside ``__enter__``/``__exit__`` so the recorded
     duration brackets exactly the ``with`` body (plus the optional JAX
     annotation enter/exit, which is what lines device kernels up with the
-    host span under ``--trace-jax``).
+    host span under ``--trace-jax``).  The annotation carries the span's
+    ``args`` as its metadata, so a profiler trace can join a span on any
+    thread to its batch.
     """
 
     __slots__ = ("_tracer", "name", "tid", "args", "_t0", "_jax")
@@ -84,7 +87,7 @@ class _Span:
     def __enter__(self) -> "_Span":
         ann = self._tracer._annotate
         if ann is not None:
-            self._jax = ann(self.name)
+            self._jax = ann(self.name, **self.args) if self.args else ann(self.name)
             self._jax.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -113,8 +116,9 @@ class Tracer:
 
     All timestamps are microseconds relative to the tracer's creation
     (``time.perf_counter`` epoch).  ``jax_annotations=True`` additionally
-    wraps every span in ``jax.profiler.TraceAnnotation`` so host spans show
-    up alongside device kernels in a ``jax.profiler`` device trace.
+    wraps every span in ``jax.profiler.TraceAnnotation`` (its ``args`` as
+    the annotation's metadata) so host spans show up alongside device
+    kernels in a ``jax.profiler`` device trace.
     """
 
     enabled = True
@@ -123,6 +127,7 @@ class Tracer:
         self._epoch = time.perf_counter()
         self._events: list[dict[str, Any]] = []
         self._lanes: dict[str, int] = {}
+        self._lane_lock = threading.Lock()  # spans also open on worker threads
         self._next_flow = itertools.count(1)
         self._annotate: Callable[[str], Any] | None = None
         if jax_annotations:
@@ -148,10 +153,13 @@ class Tracer:
         sites control the top-to-bottom layout in Perfetto."""
         tid = self._lanes.get(name)
         if tid is None:
-            tid = len(self._lanes) + 1
-            self._lanes[name] = tid
-            self._meta(tid, "thread_name", {"name": name})
-            self._meta(tid, "thread_sort_index", {"sort_index": tid})
+            with self._lane_lock:
+                tid = self._lanes.get(name)
+                if tid is None:
+                    tid = len(self._lanes) + 1
+                    self._lanes[name] = tid
+                    self._meta(tid, "thread_name", {"name": name})
+                    self._meta(tid, "thread_sort_index", {"sort_index": tid})
         return tid
 
     def _meta(self, tid: int, what: str, args: dict) -> None:

@@ -34,9 +34,8 @@ __all__ = [
 # packs ordered (packs are consumed in submission order by the batch that
 # requested them) while the submitting thread builds the index arrays and
 # issues their device transfers concurrently.
-_PACK_POOL = concurrent.futures.ThreadPoolExecutor(
-    max_workers=1, thread_name_prefix="dci-miss-pack"
-)
+PACK_LANE = "dci-miss-pack"  # the worker's thread-name prefix and trace lane
+_PACK_POOL = concurrent.futures.ThreadPoolExecutor(max_workers=1, thread_name_prefix=PACK_LANE)
 
 
 class PrefetchedMisses(typing.NamedTuple):
@@ -57,6 +56,18 @@ class PrefetchedMisses(typing.NamedTuple):
     idx: jax.Array | None
     pack_pos: jax.Array | None
     num_miss: int
+
+    @property
+    def staged_rows(self) -> int:
+        """Rows ``device_put`` moved: ``num_miss`` plus the pack's pow2
+        padding (the whole row set when every row missed)."""
+        return int(self.rows.shape[0])
+
+
+def _put_args(tracer, args: dict | None, rows: int) -> dict | None:
+    """A ``prefetch:put`` span's args: the caller's, plus the rows moved
+    (built only when tracing)."""
+    return {**(args or {}), "rows": rows} if tracer.enabled else None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,6 +155,9 @@ class FeatureStore:
         num_live: int | None = None,
         device=None,
         injector=None,
+        tracer=None,
+        lane: str = "main",
+        args: dict | None = None,
     ) -> PrefetchedMisses:
         """Stage the missed host rows for a batch onto the device.
 
@@ -177,43 +191,60 @@ class FeatureStore:
         ``injector`` (core/faults.py, optional) charges one ``prefetch``
         fault-site call before any staging work — the check precedes every
         state mutation and the staging itself is pure, so a faulted call
-        is safely retryable."""
+        is safely retryable.
+
+        ``tracer`` (core/trace.py, optional) records the staging steps as
+        spans carrying ``args`` (the caller's batch link): on ``lane``,
+        ``prefetch:scan`` (the position-map lookup), ``prefetch:index``
+        (the index arrays and their transfer) and ``prefetch:join`` (the
+        wait on the worker); on the worker's :data:`PACK_LANE` (on
+        ``lane`` when the calling thread packs: ``pack_in_thread`` off, or
+        every row missed), ``prefetch:pack`` (the zeroed pack and the row
+        copy) and ``prefetch:put`` (its ``device_put``, with the rows it
+        moves).  The spans only read the clock: they add no device sync."""
+        from repro.core.trace import resolve_tracer  # repro.core imports this module
+
+        tracer = resolve_tracer(tracer)
         if injector is not None:
             injector.check("prefetch")
         nodes = np.asarray(nodes)
-        live = nodes if num_live is None else nodes[:num_live]
-        miss = np.nonzero(self.position_np()[live] < 0)[0].astype(np.int32)
+        with tracer.span("prefetch:scan", lane=lane, args=args):
+            live = nodes if num_live is None else nodes[:num_live]
+            miss = np.nonzero(self.position_np()[live] < 0)[0].astype(np.int32)
         if miss.size == nodes.size:
             # Every row missed (e.g. no cache): the staged buffer IS the
             # whole row set — no pack, no pad, nothing to overlap.
-            return PrefetchedMisses(
-                rows=jax.device_put(self.host_np()[nodes], device),
-                idx=None,
-                pack_pos=None,
-                num_miss=int(miss.size),
-            )
+            with tracer.span("prefetch:pack", lane=lane, args=args):
+                rows = self.host_np()[nodes]
+            with tracer.span("prefetch:put", lane=lane, args=_put_args(tracer, args, nodes.size)):
+                rows = jax.device_put(rows, device)
+            return PrefetchedMisses(rows=rows, idx=None, pack_pos=None, num_miss=int(miss.size))
         bucket = pow2_bucket(miss.size, nodes.size)
+        pack_lane = PACK_LANE if pack_in_thread else lane
 
         def pack_rows():
-            rows = np.zeros((bucket, self.feat_dim), self.host_np().dtype)
-            rows[: miss.size] = self.host_np()[nodes[miss]]
-            return jax.device_put(rows, device)
+            with tracer.span("prefetch:pack", lane=pack_lane, args=args):
+                rows = np.zeros((bucket, self.feat_dim), self.host_np().dtype)
+                rows[: miss.size] = self.host_np()[nodes[miss]]
+            with tracer.span("prefetch:put", lane=pack_lane, args=_put_args(tracer, args, bucket)):
+                return jax.device_put(rows, device)
 
         rows_future = _PACK_POOL.submit(pack_rows) if pack_in_thread else None
-        idx = np.full(bucket, nodes.size, np.int32)  # pad → one past the end (dropped)
-        idx[: miss.size] = miss
-        pack_pos = np.zeros(nodes.size, np.int32)  # hit rows point at slot 0 (never read)
-        pack_pos[miss] = np.arange(miss.size, dtype=np.int32)
-        if device is not None:
-            idx, pack_pos = jax.device_put(idx, device), jax.device_put(pack_pos, device)
+        with tracer.span("prefetch:index", lane=lane, args=args):
+            idx = np.full(bucket, nodes.size, np.int32)  # pad → one past the end (dropped)
+            idx[: miss.size] = miss
+            pack_pos = np.zeros(nodes.size, np.int32)  # hit rows point at slot 0 (never read)
+            pack_pos[miss] = np.arange(miss.size, dtype=np.int32)
+            if device is not None:
+                idx, pack_pos = jax.device_put(idx, device), jax.device_put(pack_pos, device)
+            else:
+                idx, pack_pos = jnp.asarray(idx), jnp.asarray(pack_pos)
+        if rows_future is None:
+            rows = pack_rows()
         else:
-            idx, pack_pos = jnp.asarray(idx), jnp.asarray(pack_pos)
-        return PrefetchedMisses(
-            rows=rows_future.result() if rows_future is not None else pack_rows(),
-            idx=idx,
-            pack_pos=pack_pos,
-            num_miss=int(miss.size),
-        )
+            with tracer.span("prefetch:join", lane=lane, args=args):
+                rows = rows_future.result()
+        return PrefetchedMisses(rows=rows, idx=idx, pack_pos=pack_pos, num_miss=int(miss.size))
 
     def gather(
         self,

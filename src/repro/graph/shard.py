@@ -167,6 +167,11 @@ class ShardedPrefetch(typing.NamedTuple):
     parts: list
     num_miss: int
 
+    @property
+    def staged_rows(self) -> int:
+        """Rows the per-shard ``device_put`` calls moved, padding included."""
+        return sum(p.staged_rows for p in self.parts if p is not None)
+
 
 @dataclasses.dataclass
 class ShardedFeatureStore:

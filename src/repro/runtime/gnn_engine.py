@@ -98,7 +98,8 @@ class InferenceReport:
     pipeline_depth: int = 1
     prefetch: bool = False
     prefetch_seconds: float = 0.0
-    prefetched_rows: int = 0
+    prefetched_rows: int = 0  # live missed rows the prefetch stage staged
+    staged_rows: int = 0  # rows its device_puts moved, pow2 pack padding included
     # Unique-frontier accounting: ``unique_rows`` sums each batch's
     # distinct input nodes, ``gathered_rows`` the rows the feature stage
     # actually pulled (the pow2 gather buckets under dedup, every
@@ -277,6 +278,7 @@ class StreamRuntime:
         self.feat_hits = 0
         self.feat_lookups = 0
         self.prefetched_rows = 0
+        self.staged_rows = 0  # rows the prefetch device_puts moved (pack padding included)
         self.unique_rows = 0  # sum of per-batch distinct input nodes (dedup)
         self.gathered_rows = 0  # rows the feature stage actually gathered
         # Per-cache-epoch hit counters: epoch -> [adj_hits, adj_lookups,
@@ -345,6 +347,13 @@ class StreamRuntime:
                 args={"site": "host_fetch"},
             )
 
+    def _trace_kw(self, ctx) -> dict:
+        """Lane and args of a span inside ``ctx``'s stages: the batch's slot
+        lane and its index (empty when tracing is off)."""
+        if not self.tracer.enabled:
+            return {}
+        return {"lane": PipelinedExecutor.slot_lane(ctx), "args": {"batch": ctx.index}}
+
     # ------------------------------------------------------------- stages
     def sample(self, ctx):
         # Stamp the cache epoch the batch dispatches against — retire-time
@@ -392,7 +401,8 @@ class StreamRuntime:
         gather/forward programs — O(log S) distinct shapes worst case,
         each compiled once on first use."""
         dd = block.dedup
-        nu = int(dd.num_unique)
+        with self.tracer.span("sync:num_unique", **self._trace_kw(ctx)):
+            nu = int(dd.num_unique)
         bucket = pow2_bucket(nu, int(dd.unique_ids.shape[0]))
         view = (dd, nu, bucket, dd.unique_ids[:bucket])
         ctx.outputs["_dedup"] = view
@@ -412,11 +422,14 @@ class StreamRuntime:
 
     def _prefetch(self, ctx, nodes, num_live=None):
         """Stage a batch's missed host rows; returns an object exposing
-        ``num_miss`` that the consuming ``_gather`` accepts via its
-        ``prefetched`` keyword."""
-        del ctx
+        ``num_miss`` and ``staged_rows`` that the consuming ``_gather``
+        accepts via its ``prefetched`` keyword."""
         return self.pipe.caches.store.prefetch_misses(
-            nodes, num_live=num_live, injector=self.injector
+            nodes,
+            num_live=num_live,
+            injector=self.injector,
+            tracer=self.tracer,
+            **self._trace_kw(ctx),
         )
 
     def _gather(self, ctx, indices, **gather_kw):
@@ -483,13 +496,17 @@ class StreamRuntime:
         hit mask (and all accounting) still comes from ``position_map``,
         so hit/miss counts are bit-identical with prefetch on or off.
         Under ``dedup`` only the batch's DISTINCT missed rows are staged —
-        the gather consuming the pack runs over the unique bucket."""
-        if self.dedup:
-            _, nu, _, uids = self._dedup_view(ctx)
-            stage = lambda: self._prefetch(ctx, np.asarray(uids), num_live=nu)  # noqa: E731
-        else:
-            nodes = np.asarray(ctx.outputs["sample"][0].input_nodes)
-            stage = lambda: self._prefetch(ctx, nodes)  # noqa: E731
+        the gather consuming the pack runs over the unique bucket.  The
+        device→host pull of the ids is the ``prefetch:pull`` span; the
+        store's staging spans follow it (:meth:`FeatureStore.prefetch_misses`)."""
+        nu = None
+        with self.tracer.span("prefetch:pull", **self._trace_kw(ctx)):
+            if self.dedup:
+                _, nu, _, uids = self._dedup_view(ctx)
+                nodes = np.asarray(uids)
+            else:
+                nodes = np.asarray(ctx.outputs["sample"][0].input_nodes)
+        stage = lambda: self._prefetch(ctx, nodes, num_live=nu)  # noqa: E731
         if self.injector is None:
             staged = stage()
         else:
@@ -505,6 +522,7 @@ class StreamRuntime:
                 # bytes early), so the batch is NOT marked degraded.
                 return None
         self.prefetched_rows += staged.num_miss
+        self.staged_rows += staged.staged_rows
         return staged
 
     def feature(self, ctx):
@@ -563,11 +581,15 @@ class StreamRuntime:
     def record(self, ctx) -> None:
         """Host-side accounting; runs per batch, in order, after the batch's
         stage outputs (incl. the stat scalars) are ready, so the int()
-        conversions only pay a tiny device→host transfer."""
+        conversions only pay a tiny device→host transfer.  Each pull is a
+        span: ``sync:stats``, ``sync:telemetry`` (refresh only) and
+        ``sync:logits``."""
         block, bh, bt = ctx.outputs["sample"]
         feature_out = ctx.outputs["feature"]
         hit, hsum = feature_out[1], feature_out[2]
-        bh, bt, hsum, lookups = int(bh), int(bt), int(hsum), int(hit.shape[0])
+        trace_kw = self._trace_kw(ctx)
+        with self.tracer.span("sync:stats", **trace_kw):
+            bh, bt, hsum, lookups = int(bh), int(bt), int(hsum), int(hit.shape[0])
         self.adj_hits += bh
         self.adj_lookups += bt
         self.feat_hits += hsum
@@ -579,23 +601,25 @@ class StreamRuntime:
         per_epoch[3] += lookups
         per_epoch[4] += 1
         if self.telemetry is not None:
-            if self.dedup:
-                # Scatter once per unique node, weighted by its visit
-                # multiplicity — counters come out bit-identical to the
-                # per-visit form (a node's hit bit is the same for every
-                # visit within a batch).
-                dd, nu, _, uids = self._dedup_view(ctx)
-                mult = np.bincount(np.asarray(dd.inverse), minlength=nu)[:nu]
-                self.telemetry.observe_batch(
-                    np.asarray(uids)[:nu],
-                    np.asarray(feature_out[3])[:nu],
-                    block.edge_slots,
-                    multiplicities=mult,
-                )
-            else:
-                self.telemetry.observe_batch(block.input_nodes, hit, block.edge_slots)
+            with self.tracer.span("sync:telemetry", **trace_kw):
+                if self.dedup:
+                    # Scatter once per unique node, weighted by its visit
+                    # multiplicity — counters come out bit-identical to the
+                    # per-visit form (a node's hit bit is the same for every
+                    # visit within a batch).
+                    dd, nu, _, uids = self._dedup_view(ctx)
+                    mult = np.bincount(np.asarray(dd.inverse), minlength=nu)[:nu]
+                    self.telemetry.observe_batch(
+                        np.asarray(uids)[:nu],
+                        np.asarray(feature_out[3])[:nu],
+                        block.edge_slots,
+                        multiplicities=mult,
+                    )
+                else:
+                    self.telemetry.observe_batch(block.input_nodes, hit, block.edge_slots)
         if self.outputs is not None:
-            self.outputs.append(np.asarray(ctx.outputs["compute"]))
+            with self.tracer.span("sync:logits", **trace_kw):
+                self.outputs.append(np.asarray(ctx.outputs["compute"]))
 
     def epoch_hit_rates(self) -> dict[int, dict]:
         """Per-epoch hit-rate summary (one entry per cache epoch served)."""
@@ -1193,6 +1217,7 @@ class GNNInferenceEngine:
             prefetch=rt.prefetch,
             prefetch_seconds=clock.total("prefetch"),
             prefetched_rows=rt.prefetched_rows,
+            staged_rows=rt.staged_rows,
             dedup=rt.dedup,
             unique_rows=rt.unique_rows,
             gathered_rows=rt.gathered_rows,

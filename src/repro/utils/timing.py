@@ -15,7 +15,7 @@ import time
 
 import jax
 
-__all__ = ["StageClock", "Stopwatch", "timed"]
+__all__ = ["StageClock"]
 
 
 class StageClock:
@@ -78,49 +78,3 @@ class StageClock:
 
     def total(self, name: str) -> float:
         return self.totals.get(name, 0.0)
-
-
-class Stopwatch:
-    """Accumulates named wall-clock durations (seconds)."""
-
-    def __init__(self):
-        self.totals: dict[str, float] = {}
-        self.counts: dict[str, int] = {}
-
-    @contextlib.contextmanager
-    def track(self, name: str, *, sync: object = None):
-        """Time one block.  ``sync`` is a device value — or, as in
-        :meth:`StageClock.stage`, a callable producing one — blocked on
-        before the timer stops, so lazily materialized outputs are charged
-        to the block that dispatched them."""
-        t0 = time.perf_counter()
-        ok = False
-        try:
-            yield
-            ok = True
-        finally:
-            # Evaluate-then-block, and only when the body succeeded —
-            # mirrors StageClock.stage so the two timers accept the same
-            # sync argument (a failed body has no output to wait for, and
-            # an exception from the sync callable must not mask the body's).
-            if ok and sync is not None:
-                value = sync() if callable(sync) else sync
-                if value is not None:
-                    jax.block_until_ready(value)
-            dt = time.perf_counter() - t0
-            self.totals[name] = self.totals.get(name, 0.0) + dt
-            self.counts[name] = self.counts.get(name, 0) + 1
-
-    def add(self, name: str, seconds: float) -> None:
-        self.totals[name] = self.totals.get(name, 0.0) + seconds
-        self.counts[name] = self.counts.get(name, 0) + 1
-
-    def total(self, name: str) -> float:
-        return self.totals.get(name, 0.0)
-
-
-@contextlib.contextmanager
-def timed(out: dict, name: str):
-    t0 = time.perf_counter()
-    yield
-    out[name] = out.get(name, 0.0) + time.perf_counter() - t0

@@ -16,13 +16,14 @@ The load-bearing guarantees:
     x refresh grid, and the NullTracer path allocates no events.
 """
 
+import concurrent.futures
 import json
 import pathlib
 import subprocess
 import sys
 import time
 
-import jax.numpy as jnp
+import jax
 import numpy as np
 import pytest
 
@@ -36,12 +37,13 @@ from repro.core.trace import (
     summarize_trace,
     validate_trace,
 )
+from repro.graph.features import PACK_LANE, build_feature_cache, plain_feature_store
+from repro.graph.sampling import pow2_bucket
 from repro.runtime.cache_refresh import RefreshConfig
 from repro.runtime.gnn_engine import GNNInferenceEngine
 from repro.runtime.gnn_serve import MultiStreamServer, make_stream_batches
 from repro.runtime.request_queue import Request, RequestQueueServer
 from repro.runtime.sharded_serve import ShardedServer
-from repro.utils.timing import Stopwatch
 
 FANOUTS = (3, 2)
 BATCH = 64
@@ -128,6 +130,35 @@ def test_summarize_overlap_on_synthetic_spans():
     assert summarize_trace(serial.events)["overlap_fraction"] == 0.0
 
 
+def _lane_tids(events) -> dict[str, int]:
+    return {e["args"]["name"]: e["tid"] for e in events if e["ph"] == "M" and e["name"] == "thread_name"}
+
+
+def test_tracer_lanes_stay_unique_under_concurrent_creation():
+    """Spans open on worker threads too (the miss-pack worker), so lane
+    creation must not hand two lanes one tid or name one lane twice when
+    threads race to create the same lanes."""
+    tr = Tracer()
+    lanes, threads = 2000, 32
+    seen: dict[int, list[int]] = {}
+
+    def create(k):
+        seen[k] = [tr.lane(f"lane {i}") for i in range(lanes)]
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with concurrent.futures.ThreadPoolExecutor(threads) as pool:
+            for f in [pool.submit(create, k) for k in range(threads)]:
+                f.result(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    named = _lane_tids(tr.events)
+    assert sorted(named.values()) == list(range(1, lanes + 1))
+    assert sum(e["ph"] == "M" and e["name"] == "thread_name" for e in tr.events) == lanes
+    assert all(tids == [named[f"lane {i}"] for i in range(lanes)] for tids in seen.values())
+
+
 def test_null_tracer_is_free_and_shared():
     assert resolve_tracer(None) is NULL_TRACER
     tr = Tracer()
@@ -184,28 +215,6 @@ def test_metrics_prometheus_text():
     assert 'lat_ms_bucket{le="10"} 1' in text or 'lat_ms_bucket{le="10.0"} 1' in text
     assert 'lat_ms_bucket{le="+Inf"} 1' in text
     assert "lat_ms_count 1" in text
-
-
-# ----------------------------------------------------------- stopwatch fix
-
-
-def test_stopwatch_track_callable_sync():
-    sw = Stopwatch()
-    order = []
-
-    def sync():
-        order.append("sync")
-        return jnp.arange(4)
-
-    with sw.track("step", sync=sync):
-        order.append("body")
-    assert order == ["body", "sync"]
-    assert sw.total("step") > 0.0
-    # a failing body must not evaluate the sync callable
-    with pytest.raises(RuntimeError):
-        with sw.track("boom", sync=lambda: order.append("late")):
-            raise RuntimeError("x")
-    assert "late" not in order
 
 
 # --------------------------------------------------- engine / serve wiring
@@ -306,6 +315,139 @@ def test_layerwise_trace_layer_spans(small_dataset, jit_warm):
     assert any(k.startswith("chunks_total") for k in rep.metrics["counters"])
 
 
+# ------------------------------------------- prefetch and host-sync spans
+
+
+def _spans_by_batch(events, name) -> dict[int, list[dict]]:
+    out: dict[int, list[dict]] = {}
+    for e in events:
+        if e["ph"] == "X" and e["name"] == name:
+            out.setdefault(e["args"]["batch"], []).append(e)
+    return out
+
+
+def _inside(inner: dict, outer: dict) -> bool:
+    return outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+
+
+@pytest.mark.parametrize("dedup,refresh_on", [(True, False), (True, True), (False, False)])
+def test_prefetch_and_sync_spans_link_to_their_batch(small_dataset, jit_warm, dedup, refresh_on):
+    """Every staging step and host pull of a batch is a span carrying its
+    index: the caller's steps nest in the batch's ``prefetch`` stage span
+    on its slot lane, the pack and put run on the worker's lane inside the
+    same stage, and the retire-time pulls nest in the batch's span."""
+    eng = _engine(small_dataset)
+    tr = Tracer()
+    n = 4
+    rep = eng.run(
+        max_batches=n,
+        config=EngineConfig(pipeline_depth=2, dedup=dedup, prefetch=True),
+        refresh=RefreshConfig(mode="interval", interval_batches=2) if refresh_on else None,
+        collect_outputs=True,
+        tracer=tr,
+    )
+    ev = tr.events
+    assert validate_trace(ev) == []
+    worker = _lane_tids(ev)[PACK_LANE]
+    stage = _spans_by_batch(ev, "prefetch")
+    batch = _spans_by_batch(ev, "batch")
+    sample = _spans_by_batch(ev, "sample")
+    assert sorted(stage) == sorted(batch) == list(range(n))
+    for name in ("prefetch:pull", "prefetch:scan", "prefetch:index", "prefetch:join"):
+        spans = _spans_by_batch(ev, name)
+        assert sorted(spans) == list(range(n)), name
+        for b, (sp,) in spans.items():
+            assert sp["tid"] == stage[b][0]["tid"] != worker and _inside(sp, stage[b][0]), name
+    for name in ("prefetch:pack", "prefetch:put"):
+        spans = _spans_by_batch(ev, name)
+        assert sorted(spans) == list(range(n)), name
+        for b, (sp,) in spans.items():
+            assert sp["tid"] == worker and _inside(sp, stage[b][0]), name
+    puts = _spans_by_batch(ev, "prefetch:put")
+    assert sum(sp["args"]["rows"] for (sp,) in puts.values()) == rep.staged_rows
+    assert rep.staged_rows >= rep.prefetched_rows > 0
+    retire = ["sync:stats", "sync:logits"] + (["sync:telemetry"] if refresh_on else [])
+    for name in retire:
+        spans = _spans_by_batch(ev, name)
+        assert sorted(spans) == list(range(n)), name
+        for b, (sp,) in spans.items():
+            assert sp["tid"] == batch[b][0]["tid"] and _inside(sp, batch[b][0]), name
+    if not refresh_on:
+        assert not _spans_by_batch(ev, "sync:telemetry")
+    unique = _spans_by_batch(ev, "sync:num_unique")
+    assert sorted(unique) == (list(range(n)) if dedup else [])
+    for b, (sp,) in unique.items():
+        assert _inside(sp, sample[b][0])
+
+
+@pytest.mark.parametrize("pack_in_thread", [True, False])
+def test_prefetch_misses_spans_and_staged_rows(small_dataset, rng, pack_in_thread):
+    """The store's staging spans land on the caller's lane, or on the
+    worker's for the pack and its put; ``staged_rows`` is the pow2 pack
+    (the whole row set when every row missed), and tracing changes no bit."""
+    ds = small_dataset
+    counts = rng.integers(0, 6, ds.num_nodes).astype(np.int64)
+    store = build_feature_cache(ds.features, counts, capacity_bytes=200_000)
+    nodes = rng.integers(0, ds.num_nodes, 257).astype(np.int32)
+    off = store.prefetch_misses(nodes, pack_in_thread=pack_in_thread)
+    tr = Tracer()
+    on = store.prefetch_misses(
+        nodes, pack_in_thread=pack_in_thread, tracer=tr, lane="caller", args={"batch": 7}
+    )
+    np.testing.assert_array_equal(np.asarray(on.rows), np.asarray(off.rows))
+    np.testing.assert_array_equal(np.asarray(on.idx), np.asarray(off.idx))
+    assert 0 < on.num_miss == off.num_miss < nodes.size
+    assert on.staged_rows == off.staged_rows == pow2_bucket(on.num_miss, nodes.size)
+    lanes = {tid: name for name, tid in _lane_tids(tr.events).items()}
+    seen = [(e["name"], lanes[e["tid"]]) for e in tr.events if e["ph"] == "X"]
+    pack_lane = PACK_LANE if pack_in_thread else "caller"
+    caller = [("prefetch:scan", "caller"), ("prefetch:index", "caller")]
+    if pack_in_thread:
+        caller.append(("prefetch:join", "caller"))
+    assert sorted(seen) == sorted(caller + [("prefetch:pack", pack_lane), ("prefetch:put", pack_lane)])
+    assert all(e["args"]["batch"] == 7 for e in tr.events if e["ph"] == "X")
+    (put,) = [e for e in tr.events if e["name"] == "prefetch:put"]
+    assert put["args"]["rows"] == on.staged_rows
+
+    plain = plain_feature_store(ds.features)  # every row misses: no pack, no worker
+    tr = Tracer()
+    staged = plain.prefetch_misses(nodes, tracer=tr, lane="caller", args={"batch": 7})
+    assert staged.idx is None and staged.staged_rows == staged.num_miss == nodes.size
+    names = [e["name"] for e in tr.events if e["ph"] == "X"]
+    assert names == ["prefetch:scan", "prefetch:pack", "prefetch:put"]
+    assert set(_lane_tids(tr.events)) == {"caller"}
+
+
+def test_worker_spans_reach_the_profiler_on_their_own_thread(small_dataset, jit_warm, tmp_path):
+    """Under ``jax_annotations`` each span becomes a profiler annotation with
+    its args as metadata: the worker's pack and put sit on a host thread of
+    their own, and each names its batch."""
+    from jax.profiler import ProfileData
+
+    eng = _engine(small_dataset)
+    kw = dict(max_batches=2, config=EngineConfig(pipeline_depth=2, dedup=True, prefetch=True))
+    eng.run(**kw)  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run(**kw, warmup=False, tracer=Tracer(jax_annotations=True))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    found: dict[str, set] = {}  # span name -> {(host line, batch)}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("prefetch"):
+                        found.setdefault(e.name, set()).add((i, dict(e.stats).get("batch")))
+    stage_lines = {i for i, _ in found["prefetch"]}
+    for name in ("prefetch:pack", "prefetch:put"):
+        assert {b for _, b in found[name]} == {0, 1}, name
+        assert stage_lines.isdisjoint(i for i, _ in found[name]), name
+    for name in ("prefetch:pull", "prefetch:scan", "prefetch:index", "prefetch:join"):
+        assert found[name] == {(i, b) for i in stage_lines for b in (0, 1)}, name
+
+
 # ------------------------------------------------- bit-for-bit equivalence
 
 
@@ -338,6 +480,9 @@ def test_tracing_is_bit_for_bit_invisible(small_dataset, jit_warm, dedup, prefet
         assert (r_off.feat_hits, r_off.feat_lookups) == (r_on.feat_hits, r_on.feat_lookups)
         assert (r_off.adj_hits, r_off.adj_lookups) == (r_on.adj_hits, r_on.adj_lookups)
         assert r_off.gathered_rows == r_on.gathered_rows
+        assert (r_off.prefetched_rows, r_off.staged_rows) == (r_on.prefetched_rows, r_on.staged_rows)
+    assert r_on.staged_rows >= r_on.prefetched_rows
+    assert (r_on.staged_rows > 0) == prefetch
     assert validate_trace(tr.events) == []
     assert r_on.metrics is not None and r_off.metrics is None
 
