@@ -10,8 +10,10 @@ bytes moved over the slow path.
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import dataclasses
+import itertools
 import typing
 
 import jax
@@ -29,9 +31,9 @@ __all__ = [
     "refresh_feature_cache",
 ]
 
-# One shared worker for the host-side miss-row pack: the numpy fancy-index
-# copy is the heavy part of prefetch staging, and a single worker keeps the
-# packs ordered (packs are consumed in submission order by the batch that
+# One shared worker for the host-side miss-row pack: the numpy row copy is
+# the heavy part of prefetch staging, and a single worker keeps the packs
+# ordered (packs are consumed in submission order by the batch that
 # requested them) while the submitting thread builds the index arrays and
 # issues their device transfers concurrently.
 PACK_LANE = "dci-miss-pack"  # the worker's thread-name prefix and trace lane
@@ -50,12 +52,15 @@ class PrefetchedMisses(typing.NamedTuple):
     is never read) — so the kernel route can address the pack directly
     instead of rebuilding a dense miss source.  ``num_miss`` is the
     unpadded miss count — the staging accounting, so callers need not
-    re-derive the miss mask."""
+    re-derive the miss mask.  ``pack_buffer_allocs`` counts the host pack
+    buffers this call allocated (0 once the store's pack ring is warm for
+    the bucket)."""
 
     rows: jax.Array
     idx: jax.Array | None
     pack_pos: jax.Array | None
     num_miss: int
+    pack_buffer_allocs: int = 0
 
     @property
     def staged_rows(self) -> int:
@@ -64,10 +69,101 @@ class PrefetchedMisses(typing.NamedTuple):
         return int(self.rows.shape[0])
 
 
-def _put_args(tracer, args: dict | None, rows: int) -> dict | None:
-    """A ``prefetch:put`` span's args: the caller's, plus the rows moved
-    (built only when tracing)."""
-    return {**(args or {}), "rows": rows} if tracer.enabled else None
+def _span_args(tracer, args: dict | None, **extra) -> dict | None:
+    """A staging span's args: the caller's, plus ``extra`` (built only when
+    tracing)."""
+    return {**(args or {}), **extra} if tracer.enabled else None
+
+
+def _aliases(rows: jax.Array, buf: np.ndarray) -> bool:
+    """Whether ``device_put`` handed back ``buf``'s own memory.
+
+    The CPU backend puts an aligned numpy buffer zero-copy, whatever
+    ``may_alias`` says (JAX 0.9); a device with its own memory always
+    copies, so only a CPU array's pointer is read (on an accelerator the
+    read could wait on the transfer)."""
+    dev = next(iter(rows.devices()))
+    return dev.platform == "cpu" and rows.unsafe_buffer_pointer() == buf.ctypes.data
+
+
+class _PackSlot:
+    """One reused host buffer of the miss-row pack.
+
+    ``live`` rows lead the buffer; every row past them is zero, as a fresh
+    ``np.zeros`` pack would have it.  ``last`` is the array last
+    ``device_put`` from the buffer while its host→device copy may still be
+    reading the buffer: :meth:`pack` waits for it first."""
+
+    __slots__ = ("buf", "live", "last")
+
+    def __init__(self, bucket: int, feat_dim: int, dtype):
+        self.buf = np.empty((bucket, feat_dim), dtype)
+        self.buf.fill(0)  # touches every page now, not in the first packs
+        self.live = 0
+        self.last = None
+
+    def landed(self) -> bool:
+        """Whether no copy out of the buffer can still be in flight."""
+        return self.last is None or self.last.is_deleted() or self.last.is_ready()
+
+    def pack(self, table: np.ndarray, ids: np.ndarray) -> None:
+        """Rows ``table[ids]`` first, zeros after: bit-for-bit the fresh
+        pack.  Only the rows the last pack held past ``len(ids)`` are
+        re-zeroed.  ``mode="wrap"`` indexes exactly as ``table[ids]`` does
+        for every id the position-map scan accepted (an out-of-range id
+        raised there), and unlike ``"raise"`` writes ``out`` unbuffered."""
+        if not self.landed():
+            self.last.block_until_ready()
+        self.last = None
+        m = ids.shape[0]
+        if m < self.live:
+            self.buf[m : self.live] = 0
+        self.live = m
+        np.take(table, ids, axis=0, out=self.buf[:m], mode="wrap")
+
+    def device_put(self, device) -> jax.Array:
+        """Put the buffer on ``device``.  Where the backend would alias the
+        buffer (see :func:`_aliases`), put a copy instead: the next pack
+        into this slot must not rewrite a live array."""
+        rows = jax.device_put(self.buf, device)
+        if _aliases(rows, self.buf):
+            rows = jax.device_put(self.buf.copy(), device)
+        self.last = rows
+        return rows
+
+
+class _PackRing:
+    """The store's reused miss-row pack buffers: :attr:`SLOTS` per pow2
+    bucket, all allocated on the bucket's first pack (in warm-up), then
+    taken in turn.  ``allocs`` counts the buffers allocated."""
+
+    SLOTS = 3  # a pipeline of depth 2 holds two packs; the third is slack
+
+    def __init__(self, feat_dim: int, dtype):
+        self.feat_dim, self.dtype = feat_dim, dtype
+        self.slots: dict[int, collections.deque] = {}
+        self.allocs = 0
+
+    def take(self, bucket: int) -> tuple[_PackSlot, int]:
+        """The next slot for ``bucket``, and how many buffers this call
+        allocated."""
+        ring, new = self.slots.get(bucket), 0
+        if ring is None:
+            ring = self.slots[bucket] = collections.deque(
+                _PackSlot(bucket, self.feat_dim, self.dtype) for _ in range(self.SLOTS)
+            )
+            new = self.SLOTS
+            self.allocs += new
+        ring.rotate(-1)
+        return ring[-1], new
+
+    def pack(self, slot: _PackSlot, table: np.ndarray, ids: np.ndarray) -> None:
+        """:meth:`_PackSlot.pack`, after letting go of every array whose
+        copy has landed, so the ring keeps no device memory alive."""
+        for other in itertools.chain.from_iterable(tuple(self.slots.values())):
+            if other.landed():
+                other.last = None
+        slot.pack(table, ids)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,12 +190,24 @@ class FeatureStore:
         The paper's miss path reads host/UVA memory; this is the array the
         prefetch stage copies missed rows *from* with ``jax.device_put``.
         Same float bits as ``host_table``, so a prefetched row is
-        bit-identical to a direct device-side miss gather."""
+        bit-identical to a direct device-side miss gather.  Row-major: a
+        TPU hands the table back column-major, where each row is strided
+        across the whole table and ``np.take`` would copy all of it per
+        call."""
         cached = getattr(self, "_host_np", None)
         if cached is None:
-            cached = np.asarray(self.host_table)
+            cached = np.ascontiguousarray(np.asarray(self.host_table))
             object.__setattr__(self, "_host_np", cached)
         return cached
+
+    def pack_ring(self) -> _PackRing:
+        """The reused host buffers of the miss-row pack (created lazily;
+        carried to a refreshed store, which shares the host table)."""
+        ring = getattr(self, "_pack_ring", None)
+        if ring is None:
+            ring = _PackRing(self.feat_dim, self.host_np().dtype)
+            object.__setattr__(self, "_pack_ring", ring)
+        return ring
 
     def kernel_tables(self) -> tuple[jax.Array, jax.Array]:
         """``(hot, host)`` in the gather kernel's lane layout (built lazily,
@@ -176,12 +284,27 @@ class FeatureStore:
         still covers all of ``nodes``; pad miss rows read pack slot 0,
         which only ever lands in unread pad output rows.
 
+        The pack is written into a reused host buffer, not a fresh one:
+        the store's :meth:`pack_ring` keeps three buffers per bucket,
+        allocated (and their pages touched) on the bucket's first pack, so
+        in warm-up, and taken in turn on the calling thread.  Before a
+        buffer is rewritten, the array last put from it is waited on
+        (``block_until_ready``: its host→device copy may still read the
+        buffer; with a pipeline of depth 2 it finished batches ago).  Each
+        buffer remembers how many rows it held last; when fewer are live
+        now, only the rows between are re-zeroed, so the pad rows are zero
+        and the pack is bit-for-bit a fresh ``np.zeros`` one.  A backend
+        that would put the buffer zero-copy (the CPU's, for an aligned
+        buffer) is given a copy.  ``pack_buffer_allocs`` on the result
+        counts the buffers the call allocated: 0 once the bucket is warm.
+        The all-miss path keeps its own fresh row set.
+
         ``pack_in_thread`` (default on) runs the heavy part of the pack —
-        the numpy fancy-index copy of the miss rows and its ``device_put``
-        — on a worker thread while the calling thread builds the
-        ``idx``/``pack_pos`` index arrays and issues THEIR device
-        transfers; the call joins before returning, so the result (and
-        everything downstream) is bit-identical either way.
+        the row copy and its ``device_put`` — on a worker thread while the
+        calling thread builds the ``idx``/``pack_pos`` index arrays and
+        issues THEIR device transfers; the call joins before returning, so
+        the result (and everything downstream) is bit-identical either
+        way.
 
         ``device`` commits the staged buffers to a specific device — the
         sharded path stages each shard's misses onto that shard's device
@@ -199,9 +322,11 @@ class FeatureStore:
         (the index arrays and their transfer) and ``prefetch:join`` (the
         wait on the worker); on the worker's :data:`PACK_LANE` (on
         ``lane`` when the calling thread packs: ``pack_in_thread`` off, or
-        every row missed), ``prefetch:pack`` (the zeroed pack and the row
-        copy) and ``prefetch:put`` (its ``device_put``, with the rows it
-        moves).  The spans only read the clock: they add no device sync."""
+        every row missed), ``prefetch:pack`` (the wait on the buffer's last
+        transfer, the pad re-zeroing and the row copy; args add
+        ``pack_buffer_allocs``) and ``prefetch:put`` (its ``device_put``,
+        with the rows it moves).  The spans only read the clock: they add
+        no device sync."""
         from repro.core.trace import resolve_tracer  # repro.core imports this module
 
         tracer = resolve_tracer(tracer)
@@ -216,18 +341,22 @@ class FeatureStore:
             # whole row set — no pack, no pad, nothing to overlap.
             with tracer.span("prefetch:pack", lane=lane, args=args):
                 rows = self.host_np()[nodes]
-            with tracer.span("prefetch:put", lane=lane, args=_put_args(tracer, args, nodes.size)):
+            put_args = _span_args(tracer, args, rows=nodes.size)
+            with tracer.span("prefetch:put", lane=lane, args=put_args):
                 rows = jax.device_put(rows, device)
             return PrefetchedMisses(rows=rows, idx=None, pack_pos=None, num_miss=int(miss.size))
         bucket = pow2_bucket(miss.size, nodes.size)
         pack_lane = PACK_LANE if pack_in_thread else lane
+        ring = self.pack_ring()
+        slot, allocs = ring.take(bucket)
 
         def pack_rows():
-            with tracer.span("prefetch:pack", lane=pack_lane, args=args):
-                rows = np.zeros((bucket, self.feat_dim), self.host_np().dtype)
-                rows[: miss.size] = self.host_np()[nodes[miss]]
-            with tracer.span("prefetch:put", lane=pack_lane, args=_put_args(tracer, args, bucket)):
-                return jax.device_put(rows, device)
+            pack_args = _span_args(tracer, args, pack_buffer_allocs=allocs)
+            with tracer.span("prefetch:pack", lane=pack_lane, args=pack_args):
+                ring.pack(slot, self.host_np(), nodes[miss])
+            put_args = _span_args(tracer, args, rows=bucket)
+            with tracer.span("prefetch:put", lane=pack_lane, args=put_args):
+                return slot.device_put(device)
 
         rows_future = _PACK_POOL.submit(pack_rows) if pack_in_thread else None
         with tracer.span("prefetch:index", lane=lane, args=args):
@@ -244,7 +373,10 @@ class FeatureStore:
         else:
             with tracer.span("prefetch:join", lane=lane, args=args):
                 rows = rows_future.result()
-        return PrefetchedMisses(rows=rows, idx=idx, pack_pos=pack_pos, num_miss=int(miss.size))
+        return PrefetchedMisses(
+            rows=rows, idx=idx, pack_pos=pack_pos, num_miss=int(miss.size),
+            pack_buffer_allocs=allocs,
+        )
 
     def gather(
         self,
@@ -521,6 +653,9 @@ def refresh_feature_cache(
     object.__setattr__(new_store, "_position_np", new_pos_np)
     if hasattr(store, "_host_lanes"):
         object.__setattr__(new_store, "_host_lanes", store._host_lanes)
+    # The miss-row pack buffers too: the row width and dtype are the host
+    # table's, so a refresh allocates none.
+    object.__setattr__(new_store, "_pack_ring", store.pack_ring())
     return new_store, FeatureRefreshStats(
         rows_kept=int(kept_nodes.shape[0]),
         rows_inserted=int(inserted_nodes.shape[0]),

@@ -172,6 +172,11 @@ class ShardedPrefetch(typing.NamedTuple):
         """Rows the per-shard ``device_put`` calls moved, padding included."""
         return sum(p.staged_rows for p in self.parts if p is not None)
 
+    @property
+    def pack_buffer_allocs(self) -> int:
+        """Host pack buffers the per-shard stagings allocated."""
+        return sum(p.pack_buffer_allocs for p in self.parts if p is not None)
+
 
 @dataclasses.dataclass
 class ShardedFeatureStore:

@@ -100,6 +100,7 @@ class InferenceReport:
     prefetch_seconds: float = 0.0
     prefetched_rows: int = 0  # live missed rows the prefetch stage staged
     staged_rows: int = 0  # rows its device_puts moved, pow2 pack padding included
+    pack_buffer_allocs: int = 0  # host pack buffers it allocated (0 once warm)
     # Unique-frontier accounting: ``unique_rows`` sums each batch's
     # distinct input nodes, ``gathered_rows`` the rows the feature stage
     # actually pulled (the pow2 gather buckets under dedup, every
@@ -279,6 +280,7 @@ class StreamRuntime:
         self.feat_lookups = 0
         self.prefetched_rows = 0
         self.staged_rows = 0  # rows the prefetch device_puts moved (pack padding included)
+        self.pack_buffer_allocs = 0  # host pack buffers the prefetch stage allocated
         self.unique_rows = 0  # sum of per-batch distinct input nodes (dedup)
         self.gathered_rows = 0  # rows the feature stage actually gathered
         # Per-cache-epoch hit counters: epoch -> [adj_hits, adj_lookups,
@@ -422,8 +424,8 @@ class StreamRuntime:
 
     def _prefetch(self, ctx, nodes, num_live=None):
         """Stage a batch's missed host rows; returns an object exposing
-        ``num_miss`` and ``staged_rows`` that the consuming ``_gather``
-        accepts via its ``prefetched`` keyword."""
+        ``num_miss``, ``staged_rows`` and ``pack_buffer_allocs`` that the
+        consuming ``_gather`` accepts via its ``prefetched`` keyword."""
         return self.pipe.caches.store.prefetch_misses(
             nodes,
             num_live=num_live,
@@ -523,6 +525,7 @@ class StreamRuntime:
                 return None
         self.prefetched_rows += staged.num_miss
         self.staged_rows += staged.staged_rows
+        self.pack_buffer_allocs += staged.pack_buffer_allocs
         return staged
 
     def feature(self, ctx):
@@ -1218,6 +1221,7 @@ class GNNInferenceEngine:
             prefetch_seconds=clock.total("prefetch"),
             prefetched_rows=rt.prefetched_rows,
             staged_rows=rt.staged_rows,
+            pack_buffer_allocs=rt.pack_buffer_allocs,
             dedup=rt.dedup,
             unique_rows=rt.unique_rows,
             gathered_rows=rt.gathered_rows,
