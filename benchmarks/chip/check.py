@@ -136,7 +136,7 @@ def replay(served: list[Served], *, sample_blocks, dgraph, fanouts, dedup: bool,
 def check_batches(
     served: list[Served],
     *,
-    model: str,
+    model,
     params_np,
     graph,
     fanouts,
@@ -146,7 +146,8 @@ def check_batches(
     dgraph,
     dedup: bool,
 ) -> Readings:
-    """Readings over ``served`` (see the module docstring)."""
+    """Readings over ``served`` (see the module docstring); ``model`` is
+    the model's plug-in module."""
     out = Readings()
     pad_id = store.pad_node_id() if dedup else -1
     for s, block in replay(

@@ -121,29 +121,3 @@ def as_program_dataset(spec: dict, g: GraphArrays):
         val_idx=g.val_idx,
         test_idx=g.test_idx,
     )
-
-
-def make_weights(model: str, dims: list[int], seed: int):
-    """Seeded weights in the program's parameter layout (a list of layer
-    dicts: ``w_self``, ``b`` and, for GraphSAGE, ``w_nbr``), float32, made
-    on the device in one jitted call.  The biases are drawn too, not zero,
-    so that a forward which drops or misplaces the bias add fails the logit
-    comparison."""
-    import jax
-    import jax.numpy as jnp
-
-    def init(key):
-        layers = []
-        for i in range(len(dims) - 1):
-            key, k1, k2, k3 = jax.random.split(key, 4)
-            scale = 1.0 / jnp.sqrt(jnp.float32(dims[i]))
-            layer = {
-                "w_self": jax.random.normal(k1, (dims[i], dims[i + 1]), jnp.float32) * scale,
-                "b": jax.random.normal(k3, (dims[i + 1],), jnp.float32) * 0.1,
-            }
-            if model == "graphsage":
-                layer["w_nbr"] = jax.random.normal(k2, (dims[i], dims[i + 1]), jnp.float32) * scale
-            layers.append(layer)
-        return layers
-
-    return jax.jit(init)(jax.random.PRNGKey((int(seed) + 0x5EED) % (1 << 32)))

@@ -9,8 +9,12 @@ in :func:`main`, which
    checkout unless ``JAX_COMPILATION_CACHE_DIR`` is set);
 3. builds the cell from its files: ``BENCHMARK.json`` names the
    configuration file and the traffic mix (``traffic/<mix>.json``, whose
-   ``kind`` names the generator ``traffic/<kind>.py``); each per-layer
-   metric is read by ``metrics/<name>.py``;
+   ``kind`` names the generator ``traffic/<kind>.py``); the configuration's
+   ``model.name`` names the model plug-in ``models/<name>.py`` (its widths,
+   weights, reference layer and FLOP count; ``models/gcn.py`` lists what a
+   plug-in defines); each per-layer metric is read by ``metrics/<name>.py``,
+   which may list in ``COUNTERS`` the report fields it needs beyond
+   :data:`COUNTERS`;
 4. makes the graph and the weights from the seed, prepares the engine
    (presampling, the Eq. 1 split, the cache fill) and warms every shape the
    window will use: that is ``setup_s``;
@@ -55,7 +59,8 @@ def log(msg: str) -> None:
 
 
 def load_module(path: pathlib.Path, tag: str):
-    """Import one plug-in file (a traffic generator or a metric reader)."""
+    """Import one plug-in file (a traffic generator, a model or a metric
+    reader)."""
     spec = importlib.util.spec_from_file_location(f"chipbench_{tag}_{path.stem}", path)
     if spec is None or not path.is_file():
         raise FileNotFoundError(path)
@@ -72,9 +77,11 @@ class Cell:
     config: dict
     traffic: dict
     generator: object  # the traffic module
+    model: object  # the model plug-in module
     end_to_end: list[dict]
     per_layer: list[dict]
     readers: dict  # per-layer metric name -> reader module
+    counters: tuple  # report fields summed over the window
 
 
 def resolve_cell(name: str, root: pathlib.Path = ROOT, here: pathlib.Path = HERE) -> Cell:
@@ -94,16 +101,20 @@ def resolve_cell(name: str, root: pathlib.Path = ROOT, here: pathlib.Path = HERE
         return "workloads" not in metric or name in metric["workloads"]
 
     per_layer = [m for m in bench["per_layer"] if applies(m)]
+    readers = {m["name"]: load_module(here / "metrics" / f"{m['name']}.py", "metric")
+               for m in per_layer}
+    counters = COUNTERS + tuple(n for r in readers.values() for n in getattr(r, "COUNTERS", ()))
     return Cell(
         name=name,
         chips=int(w["chips"]),
         config=config,
         traffic=traffic,
         generator=generator,
+        model=load_module(here / "models" / f"{config['model']['name']}.py", "model"),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
         per_layer=per_layer,
-        readers={m["name"]: load_module(here / "metrics" / f"{m['name']}.py", "metric")
-                 for m in per_layer},
+        readers=readers,
+        counters=tuple(dict.fromkeys(counters)),
     )
 
 
@@ -151,8 +162,9 @@ class System:
     parts: dict  # set-up seconds by part
 
 
-def build(config: dict, seed: int) -> System:
-    """The graph, the weights and the prepared engine of one run."""
+def build(config: dict, model, seed: int) -> System:
+    """The graph, the weights (from the model plug-in ``model``) and the
+    prepared engine of one run."""
     from repro.core.config import EngineConfig
     from repro.runtime.gnn_engine import GNNInferenceEngine
 
@@ -165,12 +177,12 @@ def build(config: dict, seed: int) -> System:
 
     t = time.perf_counter()
     m = config["model"]
-    dims = [dspec["feat_dim"]] + [m["hidden"]] * (m["num_layers"] - 1) + [dspec["num_classes"]]
-    params = graphdata.make_weights(m["name"], dims, seed)
+    dims = model.widths(m, dspec)
+    params = model.make_weights(dims, seed, m)
     params_np = [{k: np.asarray(v) for k, v in layer.items()} for layer in params]
     engine = GNNInferenceEngine(
         dataset,
-        model=m["name"],
+        **model.engine_args(m),
         fanouts=tuple(config["fanouts"]),
         batch_size=int(config["batch_size"]),
         seed=seed,
@@ -300,16 +312,27 @@ COUNTERS = ("gathered_rows", "prefetched_rows", "unique_rows", "feat_hits", "fea
             "adj_hits", "adj_lookups", "kernel_fallbacks", "degraded_batches")
 
 
-def run_closed(system: System, gen, chunk: int, seconds: float, tracer) -> Window:
+def check_counters(names) -> None:
+    """Refuse a counter that ``InferenceReport`` has no field for."""
+    from repro.runtime.gnn_engine import InferenceReport
+
+    fields = {f.name for f in dataclasses.fields(InferenceReport)}
+    missing = [n for n in names if n not in fields]
+    if missing:
+        raise KeyError(f"counters {missing} are not fields of InferenceReport")
+
+
+def run_closed(system: System, gen, chunk: int, seconds: float, tracer,
+               counter_names: tuple) -> Window:
     eng, ecfg = system.engine, system.ecfg
-    served, counters = [], dict.fromkeys(COUNTERS, 0)
+    served, counters = [], dict.fromkeys(counter_names, 0)
     batches = 0
     t0 = time.perf_counter()
     while True:
         seeds = [next(gen) for _ in range(chunk)]
         rep = eng.run(config=ecfg, batches=seeds, warmup=False, collect_outputs=True,
                       tracer=tracer)
-        for name in COUNTERS:
+        for name in counter_names:
             counters[name] += getattr(rep, name)
         chain = len(served)
         served.extend(
@@ -374,7 +397,8 @@ def _measure(cell, seed, seconds, trace, peaks, t_start, counter) -> dict:
 
     cfg, traffic, gen_mod = cell.config, cell.traffic, cell.generator
     batch = int(cfg["batch_size"])
-    system = build(cfg, seed)
+    check_counters(cell.counters)
+    system = build(cfg, cell.model, seed)
     eng = system.engine
 
     # The window's traffic, and the batches set-up warms.
@@ -405,7 +429,8 @@ def _measure(cell, seed, seconds, trace, peaks, t_start, counter) -> dict:
     counter.armed = True
     try:
         with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN):
-            win = run_closed(system, gen, int(traffic["chunk_batches"]), window_s, tracer)
+            win = run_closed(system, gen, int(traffic["chunk_batches"]), window_s, tracer,
+                             cell.counters)
     finally:
         counter.armed = False
         if trace:
@@ -434,12 +459,26 @@ def _measure(cell, seed, seconds, trace, peaks, t_start, counter) -> dict:
             "trace": tr,
             "batches": len(win.served),
             "counters": c,
-            "row_bytes": system.dims[0] * 4,
-            "flops_per_batch": work.forward_flops(
-                cfg["model"]["name"], system.dims, cfg["fanouts"], batch
+            "row_bytes": int(cfg["dataset"]["feat_dim"]) * 4,
+            "flops_per_batch": cell.model.forward_flops(
+                system.dims, cfg["fanouts"], batch, cfg["model"]
             ),
             "peaks": peaks,
+            # what a new model's readers need: every op's device seconds by
+            # "program:op", the program's spans on every host thread
+            # (trace_reduce's ``threads``), the busy intervals and window in
+            # ns, and the shapes to count a kernel's work from
+            "ops": {k: v / 1e9 for k, v in trace_reduce.top_ops(profile, None)},
+            "spans": profile["threads"],
+            "busy": trace_reduce.busy_union(profile),
+            "window": profile["window"],
+            "model": cell.model,
+            "config": cfg,
+            "dims": system.dims,
+            "batch": batch,
         }
+        log(f"trace: flops_per_batch={ctx['flops_per_batch']} spans={len(ctx['spans'])} "
+            f"counters={json.dumps(c)}")
         metrics = {}
         for meta in cell.per_layer:
             value = cell.readers[meta["name"]].read(ctx)
@@ -452,7 +491,7 @@ def _measure(cell, seed, seconds, trace, peaks, t_start, counter) -> dict:
         result["metrics"] = metrics
         result["device"] = device
         result["breakdown"] = {
-            "device_ops": [[k, v / 1e9] for k, v in trace_reduce.top_ops(profile, 10)],
+            "device_ops": [[k, v] for k, v in list(ctx["ops"].items())[:10]],
             "idle_gaps": [[k, v / 1e9] for k, v in trace_reduce.idle_gaps(profile)[:10]],
         }
     else:
@@ -469,7 +508,7 @@ def _measure(cell, seed, seconds, trace, peaks, t_start, counter) -> dict:
     pipe = eng.pipeline
     readings = check.check_batches(
         picked,
-        model=cfg["model"]["name"],
+        model=cell.model,
         params_np=system.params_np,
         graph=system.graph,
         fanouts=tuple(cfg["fanouts"]),

@@ -1,6 +1,7 @@
 """Whole-step model FLOP utilization, in percent: the forward FLOPs a batch
-needs (``work.forward_flops``) times the batches completed in the traced
-window, over the window's length, over the chip's peak FLOP/s."""
+needs (the model plug-in's ``forward_flops``) times the batches completed
+in the traced window, over the window's length, over the chip's peak
+FLOP/s."""
 
 UNIT = "%"
 

@@ -2,14 +2,10 @@
 
 Independent of the program: they read only the sampled input frontier (the
 node ids of the deepest layer, in the ``[self | neighbours]`` layout the
-sampler documents), the feature table and the weights, and follow the
-paper's Table III models:
-
-* GraphSAGE (sum aggregator): ``h' = h_self @ W_self + (sum of the fanout
-  neighbour rows) @ W_nbr + b``;
-* GCN: ``h' = ((h_self + sum of the neighbour rows) / (fanout + 1)) @ W + b``;
-
-with ReLU between layers and none after the last.
+sampler documents), the feature table and the weights.  What a layer
+computes, its activation included, is the model plug-in's ``layer``
+(``models/<name>.py``); this module holds what every model shares: the
+walk of the frontier tree and the products at a stated precision.
 
 Frontier layout.  With fan-outs ``(f_1, ..., f_L)`` listed outermost first,
 the expansion uses them in reverse, ``rev = (f_L, ..., f_1)``.  Frontier 0
@@ -33,9 +29,9 @@ comparison.
 
 from __future__ import annotations
 
-import numpy as np
+import functools
 
-MODELS = ("graphsage", "gcn")
+import numpy as np
 
 
 def frontier_sizes(batch: int, fanouts) -> list[int]:
@@ -64,19 +60,8 @@ def matmul(a: np.ndarray, b: np.ndarray, precision: str = "highest") -> np.ndarr
     return a_hi @ b_hi + (a_hi @ b_lo + a_lo @ b_hi)
 
 
-def _layer(model, p, self_h, nbr_h, precision):
-    if model == "graphsage":
-        out = matmul(self_h, p["w_self"], precision) + matmul(
-            nbr_h.sum(axis=1), p["w_nbr"], precision
-        )
-    else:
-        agg = (self_h + nbr_h.sum(axis=1)) / np.float32(nbr_h.shape[1] + 1)
-        out = matmul(agg, p["w_self"], precision)
-    return out + p["b"]
-
-
 def forward(
-    model: str,
+    model,
     params,
     table: np.ndarray,
     input_nodes: np.ndarray,
@@ -90,15 +75,14 @@ def forward(
 
     ``params`` is a list of layer dicts of float32 numpy arrays (input layer
     first), ``table`` the float32 feature table, ``input_nodes`` the deepest
-    frontier's node ids."""
-    if model not in MODELS:
-        raise ValueError(f"unknown model {model!r}")
+    frontier's node ids, ``model`` the model's plug-in module."""
     rev = tuple(int(f) for f in reversed(tuple(fanouts)))
     sizes = frontier_sizes(batch, fanouts)
     depth = len(rev)
     if input_nodes.shape[0] != sizes[-1]:
         raise ValueError(f"frontier has {input_nodes.shape[0]} rows, expected {sizes[-1]}")
     params = [{k: np.asarray(v, np.float32) for k, v in p.items()} for p in params]
+    mm = functools.partial(matmul, precision=matmul_precision)
 
     def rows(level: int, pos: np.ndarray) -> np.ndarray:
         """Hidden rows at frontier ``level`` for positions ``pos``."""
@@ -109,14 +93,10 @@ def forward(
         below = rows(level + 1, np.concatenate([pos, nbr_pos.reshape(-1)]))
         n = pos.shape[0]
         li = depth - 1 - level  # model layer index, input layer first
-        h = _layer(
-            model,
-            params[li],
-            below[:n],
-            below[n:].reshape(n, f, below.shape[1]),
-            matmul_precision,
+        return model.layer(
+            params[li], below[:n], below[n:].reshape(n, f, below.shape[1]), matmul=mm,
+            last=level == 0,
         )
-        return np.maximum(h, np.float32(0.0)) if level > 0 else h
 
     out = [rows(0, np.arange(s, min(s + block, batch))) for s in range(0, batch, block)]
     return np.concatenate(out).astype(np.float32)

@@ -14,6 +14,7 @@ import pytest
 CHIP = pathlib.Path(__file__).resolve().parents[1]
 ROOT = CHIP.parents[1]
 sys.path.insert(0, str(CHIP))
+sys.path.insert(0, str(ROOT / "src"))
 
 import harness  # noqa: E402
 
@@ -101,6 +102,95 @@ def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
     assert cell.traffic["chunk_batches"] == 128 and callable(cell.generator.batches)
     assert set(cell.readers) == {"drain_device_ms"}
     assert sorted(m["name"] for m in cell.end_to_end) == ["nodes_per_s", "setup_s"]
+
+
+TWIN_MODEL = (CHIP / "models" / "gcn.py").read_text()  # GCN's arithmetic, and its engine_args
+TWIN_PROBE = '''"""Reads the new context: an op's device time, the program's spans on
+every thread and a counter that only this reader asks for."""
+
+UNIT = "rows"
+COUNTERS = ("staged_rows",)
+
+
+def read(ctx):
+    packs = [s for s in ctx["spans"] if s[1] == "prefetch:pack"]
+    forward = sum(v for k, v in ctx["ops"].items() if k.startswith("jit_forward"))
+    shapes_known = ctx["dims"][0] == ctx["config"]["dataset"]["feat_dim"] and ctx["batch"] > 0
+    if not packs or forward <= 0 or not shapes_known or not hasattr(ctx["model"], "layer"):
+        return None
+    return ctx["counters"]["staged_rows"] / len(packs)
+'''
+
+
+def _new_model_cell(tmp_path, model_name):
+    """A copy of the benchmark with a tiny configuration of ``model_name``, a
+    cell and a reader, all new files and entries."""
+    chip = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(CHIP, chip, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    bench = json.loads(json.dumps(BENCH))
+    cfg = json.loads((CHIP / "tests" / "data" / "tiny-sage.json").read_text())
+    cfg["name"], cfg["model"] = "twin-tiny", dict(cfg["model"], name=model_name, aggregator="mean")
+    (chip / "configs" / "twin-tiny.json").write_text(json.dumps(cfg))
+    (chip / "metrics" / "twin_probe.py").write_text(TWIN_PROBE)
+    bench["configs"].append({"name": "twin-tiny", "source": "https://arxiv.org/abs/2503.01281",
+                             "file": "benchmarks/chip/configs/twin-tiny.json", "reduced": [],
+                             "why": "test"})
+    bench["workloads"].append({"name": "twin-tiny.offline", "config": "twin-tiny",
+                               "traffic": "offline", "chips": 1, "why": "test"})
+    bench["end_to_end"][0]["workloads"].append("twin-tiny.offline")
+    bench["per_layer"].append({"name": "twin_probe", "unit": "rows", "better": "higher",
+                               "source": "program_counter", "layer": "test",
+                               "moves": "nodes_per_s", "workloads": ["twin-tiny.offline"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return chip
+
+
+def _with_device_ops(load_xplane):
+    """The CPU backend's trace has no device plane: give each layer of
+    ``layers.json`` one op inside the window, and keep the rest as traced."""
+    def load(trace_dir):
+        prof = load_xplane(trace_dir)
+        w0, w1 = prof["window"]
+        step = (w1 - w0) / 4
+        prof["ops"] = [["/device:TPU:0", prog, "fusion", w0 + i * step, step / 2]
+                       for i, prog in enumerate(("jit_sample_blocks", "jit_gather", "jit_forward"))]
+        return prof
+    return load
+
+
+def test_a_new_model_needs_only_new_files_and_entries(tmp_path, monkeypatch):
+    """A model plug-in, a configuration, a cell and a reader that declares a
+    counter of its own, all added as files and entries: the cell resolves
+    and a traced run of it on the CPU reads ``correct`` true and gives the
+    reader its ops, spans and counter, with no file of the benchmark edited."""
+    import trace_reduce
+
+    chip = _new_model_cell(tmp_path, "gcn-twin")
+    (chip / "models" / "gcn-twin.py").write_text(TWIN_MODEL)
+    cell = harness.resolve_cell("twin-tiny.offline", root=tmp_path, here=chip)
+    assert cell.model.engine_args(cell.config["model"]) == {"model": "gcn"}
+    assert "staged_rows" in cell.counters and set(cell.readers) == {"twin_probe"}
+    monkeypatch.setattr(trace_reduce, "load_xplane", _with_device_ops(trace_reduce.load_xplane))
+    peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9}
+    res = harness.measure(cell, 2147483911, 0.5, True, peaks=peaks)
+    assert res["correct"], res["checks"]
+    assert res["metrics"]["twin_probe"]["value"] > 0
+    for path in CHIP.rglob("*.*"):
+        rel = path.relative_to(CHIP)
+        if rel.parts[0] != "tests" and "__pycache__" not in rel.parts:
+            assert (chip / rel).read_bytes() == path.read_bytes(), rel
+
+
+def test_an_unknown_model_fails_with_the_missing_path(tmp_path):
+    chip = _new_model_cell(tmp_path, "no-such-model")
+    with pytest.raises(FileNotFoundError, match="models/no-such-model.py"):
+        harness.resolve_cell("twin-tiny.offline", root=tmp_path, here=chip)
+
+
+def test_a_reader_counter_must_be_a_report_field():
+    harness.check_counters(harness.COUNTERS + ("staged_rows",))
+    with pytest.raises(KeyError, match="no_such_rows"):
+        harness.check_counters(("gathered_rows", "no_such_rows"))
 
 
 def test_run_without_a_tpu_exits_nonzero_and_prints_no_result():

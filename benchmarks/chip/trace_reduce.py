@@ -10,6 +10,11 @@ a plain, JSON-safe profile:
 * ``host``: the named host events (``[name, start_ns, dur_ns]``) of the
   thread that ran the window: the program's ``Tracer(jax_annotations=True)``
   spans and the runtime's dispatch events;
+* ``threads``: the named host events of every host thread that overlap the
+  window, as ``[line, name, start_ns, dur_ns, batch]``: ``line`` numbers
+  the thread's line in the trace (threads share names: the ``dci-miss-pack``
+  worker's line and the window's are both ``python``), ``batch`` is the
+  ``batch`` of the span's annotation metadata, or None;
 * ``window``: ``[start_ns, end_ns]`` of the host annotation that brackets
   the traced window.
 
@@ -22,7 +27,9 @@ is enough to test it:
   that matches the op's program name;
 * ``idle_gaps``: each stretch of the window with no op running, labelled
   by the innermost host span open at its middle;
-* ``top_ops``: the device operations that took most time.
+* ``top_ops``: the device operations that took most time;
+* ``busy_union`` and ``idle_within``: the window's busy intervals, and the
+  device-idle time inside a set of host spans.
 """
 
 from __future__ import annotations
@@ -48,6 +55,8 @@ def load_xplane(trace_dir: str) -> dict:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = ProfileData.from_file(paths[-1])
     ops, host, window = [], [], None
+    host_lines = [line for plane in data.planes if plane.name.startswith("/host:")
+                  for line in plane.lines]
     for plane in data.planes:
         if plane.name.startswith("/device:"):
             lines = {line.name: list(line.events) for line in plane.lines}
@@ -59,19 +68,25 @@ def load_xplane(trace_dir: str) -> dict:
                 module = dict(e.stats).get("hlo_module") or _enclosing(modules, e.start_ns)
                 op = e.name.split(" = ")[0]
                 ops.append([plane.name, str(module), op, e.start_ns, e.duration_ns])
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                events = list(line.events)
-                if not any(e.name == WINDOW_SPAN for e in events):
-                    continue  # only the thread that ran the window: its spans say what it did
-                for e in events:
-                    if e.name == WINDOW_SPAN:
-                        window = [e.start_ns, e.start_ns + e.duration_ns]
-                    elif not e.name.startswith("$") and e.duration_ns > 0:
-                        host.append([e.name, e.start_ns, e.duration_ns])
+    for line in host_lines:
+        events = list(line.events)
+        if not any(e.name == WINDOW_SPAN for e in events):
+            continue  # only the thread that ran the window: its spans say what it did
+        for e in events:
+            if e.name == WINDOW_SPAN:
+                window = [e.start_ns, e.start_ns + e.duration_ns]
+            elif not e.name.startswith("$") and e.duration_ns > 0:
+                host.append([e.name, e.start_ns, e.duration_ns])
     if window is None:
         raise ValueError(f"no {WINDOW_SPAN!r} span in the trace")
-    return {"window": window, "ops": ops, "host": host}
+    threads = [
+        [i, e.name, e.start_ns, e.duration_ns, dict(e.stats).get("batch")]
+        for i, line in enumerate(host_lines)
+        for e in line.events
+        if not e.name.startswith("$") and e.duration_ns > 0
+        and e.start_ns < window[1] and e.start_ns + e.duration_ns > window[0]
+    ]
+    return {"window": window, "ops": ops, "host": host, "threads": threads}
 
 
 def _enclosing(modules, t) -> str:
@@ -136,15 +151,20 @@ def layer_ns(profile: dict, layer_map: dict) -> dict[str, float]:
     return dict(out)
 
 
+def busy_union(profile: dict) -> list[tuple[float, float]]:
+    """The intervals inside the window in which an op ran on any device."""
+    return union(
+        iv for _dev, _m, _o, s, d in profile["ops"] if (iv := _clip(s, d, profile["window"]))
+    )
+
+
 def idle_gaps(profile: dict) -> list[tuple[str, float]]:
     """Idle time inside the window by the host span open in each gap,
     ``[(label, ns)]`` largest first.
 
     A gap's label is the shortest host span that covers its midpoint."""
     w0, w1 = profile["window"]
-    busy = union(
-        iv for dev, _m, _o, s, d in profile["ops"] if (iv := _clip(s, d, profile["window"]))
-    )
+    busy = busy_union(profile)
     gaps, t = [], w0
     for a, b in busy:
         if a > t:
@@ -167,8 +187,9 @@ def idle_gaps(profile: dict) -> list[tuple[str, float]]:
     return sorted(totals.items(), key=lambda kv: -kv[1])
 
 
-def top_ops(profile: dict, n: int = 10) -> list[tuple[str, float]]:
-    """``[(program:op, ns)]`` of the ops that took most time in the window."""
+def top_ops(profile: dict, n: int | None = 10) -> list[tuple[str, float]]:
+    """``[(program:op, ns)]`` of the ops that took most time in the window;
+    every op when ``n`` is None."""
     totals: dict[str, float] = collections.defaultdict(float)
     for _dev, module, op, start, dur in profile["ops"]:
         iv = _clip(start, dur, profile["window"])
@@ -177,11 +198,48 @@ def top_ops(profile: dict, n: int = 10) -> list[tuple[str, float]]:
     return sorted(totals.items(), key=lambda kv: -kv[1])[:n]
 
 
+def window_line(spans) -> int | None:
+    """The line of ``threads`` spans that ran the window."""
+    return next((line for line, name, *_ in spans if name == WINDOW_SPAN), None)
+
+
+def span_intervals(spans, names, line: int | None = None) -> list[tuple[float, float]]:
+    """``(start_ns, end_ns)`` of the ``threads`` spans named in ``names``
+    (on ``line`` only, where it is given)."""
+    return [(s, s + d) for ln, name, s, d, _batch in spans
+            if name in names and (line is None or ln == line)]
+
+
+def covered_ns(window, intervals) -> float:
+    """Time inside ``window`` that the union of ``intervals`` covers."""
+    cover = union(iv for s, e in intervals if (iv := _clip(s, e - s, window)))
+    return sum(b - a for a, b in cover)
+
+
+def idle_within(busy, window, intervals) -> float:
+    """Device-idle ns inside the union of ``intervals`` (``(start_ns,
+    end_ns)``, clipped to ``window``): what they and ``busy`` (a
+    :func:`busy_union`) cover together, less what ``busy`` covers alone."""
+    return covered_ns(window, [*intervals, *busy]) - covered_ns(window, busy)
+
+
 def trim(profile: dict, max_ops: int = 400, max_host: int = 400) -> dict:
     """A small copy of a profile (the first ops and host spans of its
-    window), for keeping as a test fixture."""
+    window), for keeping as a test fixture.  Of ``threads`` it keeps the
+    lines of the program's threads (the window's, and every line with a
+    span that carries a batch), at most ``max_host`` spans each."""
     ops = sorted(profile["ops"], key=lambda o: o[3])[:max_ops]
     host = sorted(profile["host"], key=lambda h: h[1])[:max_host]
     end = max([o[3] + o[4] for o in ops] + [profile["window"][0]])
-    return {"window": [profile["window"][0], min(end, profile["window"][1])], "ops": ops,
-            "host": [h for h in host if h[1] < end]}
+    out = {"window": [profile["window"][0], min(end, profile["window"][1])], "ops": ops,
+           "host": [h for h in host if h[1] < end]}
+    if "threads" in profile:
+        spans = [t for t in sorted(profile["threads"], key=lambda t: t[2]) if t[2] < end]
+        keep = {t[0] for t in spans if t[4] is not None or t[1] == WINDOW_SPAN}
+        per_line: dict[int, int] = collections.Counter()
+        out["threads"] = []
+        for t in spans:
+            if t[0] in keep and per_line[t[0]] < max_host:
+                per_line[t[0]] += 1
+                out["threads"].append(t)
+    return out
