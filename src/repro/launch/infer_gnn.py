@@ -22,6 +22,7 @@ from repro.core.config import INFERENCE_MODES, ServeConfig
 from repro.core.policies import ADMISSION_POLICIES, POLICIES
 from repro.core.trace import MetricsRegistry, Tracer
 from repro.graph import load_dataset
+from repro.models.gnn import MODELS
 from repro.runtime.cache_refresh import MODES as REFRESH_MODES
 from repro.runtime.gnn_engine import GNNInferenceEngine
 from repro.runtime.gnn_serve import MultiStreamServer, make_stream_batches
@@ -44,7 +45,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="ogbn-products")
     ap.add_argument("--policy", default="dci", choices=sorted(POLICIES))
-    ap.add_argument("--model", default="graphsage", choices=("graphsage", "gcn"))
+    ap.add_argument("--model", default="graphsage", choices=MODELS)
     ap.add_argument("--fanouts", default="15,10,5")
     ap.add_argument("--batch-size", type=int, default=1024)
     ap.add_argument("--cache-mb", type=float, default=2.0)

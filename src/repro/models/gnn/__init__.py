@@ -1,3 +1,19 @@
-from repro.models.gnn.models import MODELS, forward, forward_layer, init_params
+from repro.models.gnn.models import (
+    LAYER_KINDS,
+    MODELS,
+    attn_edges,
+    forward,
+    forward_layer,
+    init_params,
+    layer_kind,
+)
 
-__all__ = ["MODELS", "forward", "forward_layer", "init_params"]
+__all__ = [
+    "LAYER_KINDS",
+    "MODELS",
+    "attn_edges",
+    "forward",
+    "forward_layer",
+    "init_params",
+    "layer_kind",
+]

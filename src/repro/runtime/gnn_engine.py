@@ -109,6 +109,10 @@ class InferenceReport:
     dedup: bool = False
     unique_rows: int = 0
     gathered_rows: int = 0
+    # Edge scores the forward computed, heads counted (Σ over layers of
+    # S_dst × (fanout + 1) × heads; 0 for models without attention),
+    # counted on the host from static shapes.
+    attn_edges: int = 0
     # Online-refresh accounting (empty/None when refresh is off, keeping
     # the report — and every baseline comparison over it — unchanged):
     refresh_events: list = dataclasses.field(default_factory=list)
@@ -283,6 +287,7 @@ class StreamRuntime:
         self.pack_buffer_allocs = 0  # host pack buffers the prefetch stage allocated
         self.unique_rows = 0  # sum of per-batch distinct input nodes (dedup)
         self.gathered_rows = 0  # rows the feature stage actually gathered
+        self.attn_edges = 0  # edge scores the forward computed, heads counted
         # Per-cache-epoch hit counters: epoch -> [adj_hits, adj_lookups,
         # feat_hits, feat_lookups, batches].  With refresh off everything
         # lands in epoch 0 and the lifetime counters above tell the whole
@@ -597,6 +602,9 @@ class StreamRuntime:
         self.adj_lookups += bt
         self.feat_hits += hsum
         self.feat_lookups += lookups
+        self.attn_edges += gnn_models.attn_edges(
+            self.params, self.model, self.fanouts, np.shape(ctx.payload)[0]
+        )
         per_epoch = self.epoch_counters.setdefault(ctx.epoch, [0, 0, 0, 0, 0])
         per_epoch[0] += bh
         per_epoch[1] += bt
@@ -1225,6 +1233,7 @@ class GNNInferenceEngine:
             dedup=rt.dedup,
             unique_rows=rt.unique_rows,
             gathered_rows=rt.gathered_rows,
+            attn_edges=rt.attn_edges,
             refresh_events=list(manager.events) if manager is not None else [],
             epoch_hits=rt.epoch_hit_rates() if manager is not None else None,
             config=resolved_cfg,

@@ -267,11 +267,12 @@ def _probe_gather_seconds(store: FeatureStore, ids: jax.Array, reps: int = 2) ->
     return best
 
 
-def _intermediate_width(params) -> int:
+def _intermediate_width(params, model: str) -> int:
     """Widest intermediate layer output — what sizes the embedding cache's
     need bound (the spill tables are [N, dims[k]] for k = 1..L-1)."""
-    widths = [p["w_self"].shape[1] for p in params[:-1]]
-    return max(widths) if widths else int(params[-1]["w_self"].shape[1])
+    out_dim = gnn_models.layer_kind(model).out_dim
+    widths = [out_dim(p) for p in params[:-1]]
+    return max(widths) if widths else out_dim(params[-1])
 
 
 def run_layerwise(
@@ -310,7 +311,7 @@ def run_layerwise(
     # one-live-at-a-time) embedding cache, from probed chunk gather laps.
     t_prep = time.perf_counter()
     total_bytes = pipe.caches.allocation.total_bytes if pipe.caches.allocation else 0
-    embed_width = _intermediate_width(params)
+    embed_width = _intermediate_width(params, model)
     embed_row_bytes = embed_width * 4
     alloc = None
     feat_store = pipe.caches.store
@@ -350,8 +351,8 @@ def run_layerwise(
 
     for layer in range(num_layers):
         store = feat_store if layer == 0 else build_store
-        relu = layer < num_layers - 1
-        out_dim = int(params[layer]["w_self"].shape[1])
+        activate = layer < num_layers - 1
+        out_dim = gnn_models.layer_kind(model).out_dim(params[layer])
         out_host = np.empty((n, out_dim), np.float32)
         pad_id = max(store.pad_node_id(), 0)
         hits_key = "feat_hits" if layer == 0 else "embed_hits"
@@ -379,7 +380,7 @@ def run_layerwise(
             state["prefetched_rows"] += staged.num_miss
             return staged
 
-        def compute_fn(ctx, layer=layer, relu=relu):
+        def compute_fn(ctx, layer=layer, activate=activate):
             spec, _ = ctx.payload
             feats = ctx.outputs["gather"][0]
             return gnn_models.forward_layer(
@@ -390,7 +391,7 @@ def run_layerwise(
                 spec.degrees,
                 model=model,
                 num_dst=chunk_size,
-                relu=relu,
+                activate=activate,
             )
 
         def on_retire(ctx, out_host=out_host, hk=hits_key, lk=lookups_key):
@@ -442,7 +443,7 @@ def run_layerwise(
                     spec.degrees,
                     model=model,
                     num_dst=chunk_size,
-                    relu=relu,
+                    activate=activate,
                 )
             )
         # One enclosing span per layer pass on the "layers" lane; the
@@ -456,7 +457,7 @@ def run_layerwise(
         ):
             executor.run(payloads)
 
-        if relu:
+        if activate:
             # Next layer's input store: the spilled table behind a fresh
             # embedding cache.  Only one is live at a time, so it gets the
             # full per-layer embedding share.

@@ -73,14 +73,39 @@ def _ragged_graph() -> CSCGraph:
     return CSCGraph(col_ptr=col_ptr, row_index=row_index)
 
 
+def _dense_gat_layer(g, h, p) -> np.ndarray:
+    """One GAT layer, a per-node loop over {self} ∪ in-neighbors (multi-edges
+    counted once each): per-head softmax of LeakyReLU_0.2 scores, heads
+    concatenated (or averaged where ``b`` is one head wide), plus the skip."""
+    p = {k: np.asarray(v, np.float64) for k, v in p.items()}
+    heads, ch = p["att_src"].shape
+    z = (h @ p["w"]).reshape(-1, heads, ch)
+    out = np.zeros((g.num_nodes, heads, ch))
+    for v in range(g.num_nodes):
+        e0, e1 = int(g.col_ptr[v]), int(g.col_ptr[v + 1])
+        srcs = np.concatenate([[v], np.asarray(g.row_index[e0:e1])]).astype(np.int64)
+        e = (z[srcs] * p["att_src"]).sum(-1) + (z[v] * p["att_dst"]).sum(-1)
+        e = np.where(e > 0, e, 0.2 * e)
+        a = np.exp(e - e.max(axis=0))
+        a /= a.sum(axis=0)
+        out[v] = (a[..., None] * z[srcs]).sum(axis=0)
+    merged = out.reshape(g.num_nodes, -1) if p["b"].shape[0] == heads * ch else out.mean(axis=1)
+    return merged + p["b"] + h @ p["w_skip"] + p["b_skip"]
+
+
 def _dense_reference(dataset, params, model: str) -> np.ndarray:
     """Straight numpy layer chain over full in-neighborhoods (agg = 0 for
-    zero-degree nodes, matching forward_layer's segment_sum semantics)."""
+    zero-degree nodes, matching forward_layer's segment_sum semantics; a
+    GAT node without in-edges attends to itself alone)."""
     g = dataset.graph
     n = g.num_nodes
     deg = np.diff(g.col_ptr).astype(np.float64)
     h = dataset.features.astype(np.float64)
     for li, p in enumerate(params):
+        if model == "gat":
+            out = _dense_gat_layer(g, h, p)
+            h = np.where(out > 0, out, np.expm1(out)) if li < len(params) - 1 else out
+            continue
         agg = np.zeros_like(h)
         for v in range(n):
             e0, e1 = int(g.col_ptr[v]), int(g.col_ptr[v + 1])
@@ -98,16 +123,25 @@ def _dense_reference(dataset, params, model: str) -> np.ndarray:
 
 
 def _params(dataset, model, n_layers, seed=0, hidden=6):
+    """Seeded parameters; GAT at 2 heads (hidden layers concatenate, the
+    last averages), with drawn biases so that a dropped one shows."""
     import jax
 
-    return gnn_models.init_params(
+    params = gnn_models.init_params(
         jax.random.PRNGKey(seed),
         model,
         dataset.spec.feat_dim,
         dataset.spec.num_classes,
         hidden=hidden,
         n_layers=n_layers,
+        heads=2 if model == "gat" else None,
     )
+    if model == "gat":
+        rng = np.random.default_rng(seed)
+        for p in params:
+            for k in ("b", "b_skip"):
+                p[k] = jnp.asarray(rng.standard_normal(p[k].shape).astype(np.float32) * 0.1)
+    return params
 
 
 def _layerwise_engine(dataset, *, model="graphsage", fanouts=(3, 3), cache_bytes=4096, seed=0):
@@ -164,7 +198,7 @@ def test_plan_chunks_geometry(chunk_size):
 # ------------------------------------------------------------- equivalence
 
 
-@pytest.mark.parametrize("model", ["graphsage", "gcn"])
+@pytest.mark.parametrize("model", ["graphsage", "gcn", "gat"])
 def test_matches_dense_reference(model):
     ds = _dataset_from_graph(_ragged_graph())
     eng = _layerwise_engine(ds, model=model, fanouts=(2, 2), cache_bytes=2048)
@@ -174,7 +208,7 @@ def test_matches_dense_reference(model):
     np.testing.assert_allclose(rep.outputs, ref, **TOL)
 
 
-@pytest.mark.parametrize("model", ["graphsage", "gcn"])
+@pytest.mark.parametrize("model", ["graphsage", "gcn", "gat"])
 def test_matches_full_neighborhood_sampled_forward(model):
     """On a d-regular graph with fanout == d, the deterministic
     full-neighborhood enumeration takes every in-edge exactly once, so the

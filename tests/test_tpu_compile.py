@@ -3,7 +3,7 @@
 The TPU compiler refuses what interpret mode accepts: tile-misaligned
 slices, scalar-memory overflow, programs that do not fit HBM.  These tests
 compile the gather kernels at the Table II widths and at real frontier row
-counts, and the GraphSAGE forward at a full batch-1024 frontier, through
+counts, and the GraphSAGE and GAT forwards at a full batch-1024 frontier, through
 ``.lower(...).compile()`` on shapes alone.  The topology is described in a
 fixture (never at import), so every test worker collects the same tests
 and only the worker running this file loads the TPU compiler.
@@ -95,4 +95,29 @@ def test_graphsage_forward_fits_one_chip(one_chip):
     assert used < HBM_BYTES, f"forward needs {used} B of HBM"
     assert jax.eval_shape(
         functools.partial(forward, model="graphsage", fanouts=FANOUTS), params, feats
+    ).shape == (BATCH, 47)
+
+
+def test_gat_forward_fits_one_chip(one_chip):
+    """GAT at PyG's ogbn-products widths (4 heads x 128, skips) in the
+    unique-frontier form the engine runs with dedup: 2^20 distinct rows
+    rebuilt into the 1,081,344-row frontier.  The projected neighbour block
+    alone is 1,013,760 x 512 floats (2.1 GB)."""
+    params = jax.eval_shape(
+        functools.partial(init_params, model="gat", in_dim=100, num_classes=47),
+        jax.random.PRNGKey(0),
+    )
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), params)
+    feats = _sds((2**20, 100), jnp.float32, one_chip)
+    inverse = _sds((FRONTIER,), jnp.int32, one_chip)
+    with jax.default_matmul_precision("highest"):
+        compiled = forward.lower(
+            params, feats, model="gat", fanouts=FANOUTS, inverse_index=inverse
+        ).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    assert used < HBM_BYTES // 2, f"forward needs {used} B of HBM"
+    assert jax.eval_shape(
+        functools.partial(forward, model="gat", fanouts=FANOUTS), params, feats,
+        inverse_index=inverse,
     ).shape == (BATCH, 47)

@@ -448,6 +448,27 @@ def test_worker_spans_reach_the_profiler_on_their_own_thread(small_dataset, jit_
         assert found[name] == {(i, b) for i in stage_lines for b in (0, 1)}, name
 
 
+@pytest.mark.parametrize("model,per_batch", [("gat", 2 * (192 * 4 + 64 * 3)), ("graphsage", 0)])
+def test_attn_edges_counts_the_forward_edge_scores(small_dataset, model, per_batch):
+    """``attn_edges``: Σ over layers of S_dst × (fanout + 1) × heads per
+    batch, from static shapes.  At fan-outs (3, 2) and 64 seeds the layers
+    map 768 → 192 rows (fan-out 3) and 192 → 64 (fan-out 2); GAT here has
+    2 heads, GraphSAGE no per-edge scores."""
+    from repro.models import gnn as gnn_models
+
+    params = gnn_models.init_params(
+        jax.random.PRNGKey(0), model, small_dataset.spec.feat_dim,
+        small_dataset.spec.num_classes, hidden=8, n_layers=2, heads=2,
+    )
+    eng = GNNInferenceEngine(small_dataset, model=model, fanouts=FANOUTS, batch_size=BATCH,
+                             params=params)
+    eng.prepare("dci", **KW)
+    rep = eng.run(max_batches=3, config=EngineConfig(pipeline_depth=2, dedup=True, prefetch=True),
+                  tracer=Tracer())
+    assert rep.num_batches == 3
+    assert rep.attn_edges == 3 * per_batch
+
+
 # ------------------------------------------------- bit-for-bit equivalence
 
 
