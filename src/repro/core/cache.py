@@ -1,9 +1,10 @@
 """DualCache — the runtime bundle of DCI's two caches, versioned by epoch.
 
-``DualCache`` owns the device-resident adjacency cache (inside
-``DeviceGraph``) and the feature cache (inside ``FeatureStore``) plus the
-allocation that produced them.  It is what the inference engine actually
-runs against; policies (core/policies.py) are factories for it.
+``DualCache`` owns the adjacency cache (the host ``AdjCache``, whose hit
+lengths ride in ``DeviceGraph.cached_len``) and the feature cache (inside
+``FeatureStore``) plus the allocation that produced them.  It is what the
+inference engine actually runs against; policies (core/policies.py) are
+factories for it.
 
 Since the online refresh subsystem (runtime/cache_refresh.py) it is a
 *versioned, mutable-by-delta* runtime object rather than a frozen value:
@@ -162,25 +163,11 @@ class DualCache:
             new_store, feat_stats = refresh_feature_cache(
                 self.store, node_counts, allocation.feat_bytes
             )
-            cache_row = new_adj.cache_row_index
-            # Pad the device copy to a grow-only power-of-two physical size:
-            # the sampler's programs specialize on this array's SHAPE, so an
-            # exact-size copy would force a sample_blocks recompile on every
-            # epoch (and the recompile would land inside the next window's
-            # sample lap, feeding back into the Eq. 1 ratio).  Padded tail
-            # entries are never read — the hit test is ``r < cached_len``.
-            phys = max(self.dgraph.cache_row_index.shape[0], 1)
-            while phys < cache_row.shape[0]:
-                phys *= 2
-            if cache_row.shape[0] < phys:
-                cache_row = np.concatenate(
-                    [cache_row, np.zeros(phys - cache_row.shape[0], np.int32)]
-                )
+            # Only the hit lengths go to the device: the sampler reads every
+            # neighbor from the resident sorted ``row_index``, and
+            # ``cached_len`` keeps its int32[N] shape, so no epoch recompiles.
             self.dgraph = dataclasses.replace(
-                self.dgraph,
-                cache_ptr=jnp.asarray(new_adj.cache_ptr, jnp.int32),
-                cache_row_index=jnp.asarray(cache_row, jnp.int32),
-                cached_len=jnp.asarray(new_adj.cached_len, jnp.int32),
+                self.dgraph, cached_len=jnp.asarray(new_adj.cached_len, jnp.int32)
             )
             self.store = new_store
             if injector is not None:

@@ -105,10 +105,12 @@ def node_visit_totals(graph: CSCGraph, edge_counts: np.ndarray) -> np.ndarray:
 
 @dataclasses.dataclass(frozen=True)
 class AdjCache:
-    """Device-resident prefix cache of the two-level-sorted CSC (Fig. 6c).
+    """Prefix cache of the two-level-sorted CSC (Fig. 6c), on the host.
 
     ``cached_len[v]`` elements of node ``v``'s sorted neighbor list live in
-    the cache; the sampler's hit test is ``slot < cached_len[v]``.
+    the cache; the sampler's hit test is ``slot < cached_len[v]``.  While
+    the whole sorted CSC is resident on the device, ``cached_len`` is the
+    only part the device needs (``repro.graph.sampling.device_graph``).
     """
 
     cache_ptr: np.ndarray  # int64[N+1] offsets into cache_row_index
@@ -218,12 +220,13 @@ def refresh_adj_cache(
       * only changed nodes' segments are re-gathered from the sorted host
         copy;
       * the device-resident ``col_ptr`` / ``row_index`` (the O(E) arrays)
-        are never touched or re-uploaded — only the cache-sized arrays
-        move, which is the bounded pause the refresh subsystem promises.
+        are never touched or re-uploaded — only the O(N) ``cached_len``
+        moves to the device, which is the bounded pause the refresh
+        subsystem promises.
 
     Freezing the level-2 (within-node) order also keeps sampling
-    bit-identical across epochs: a cache hit reads the same neighbor the
-    sorted host copy holds at that slot, so a refresh changes hit
+    bit-identical across epochs: a cached slot holds the same neighbor the
+    sorted copy holds at that slot, so a refresh changes hit
     accounting and byte movement, never sampled blocks or outputs.
     """
     n = graph.num_nodes

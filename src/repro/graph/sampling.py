@@ -1,11 +1,15 @@
-"""Vectorized neighbor sampling (paper §II-B) with optional adjacency cache.
+"""Vectorized neighbor sampling (paper §II-B) with adjacency-cache accounting.
 
 The sampler is pure JAX and jittable: for every seed node it draws
 ``fanout`` uniform slots ``r ~ U[0, deg)`` and reads the neighbor at that
-slot.  With DCI's adjacency cache active, the hit test is the paper's
-single compare ``r < cached_len[v]`` (Fig. 6c): hits read from the compact
-cache arrays, misses fall back to the (two-level-sorted) host CSC — the
-UVA path on the paper's GPU, the HBM full-table path on TPU.
+slot from ``row_index``.  With DCI's adjacency cache active, ``row_index``
+is the two-level-sorted CSC and the hit test is the paper's single compare
+``r < cached_len[v]`` (Fig. 6c).  On the paper's GPU a hit reads the
+compact cache and a miss the host CSC behind UVA.  Here the full sorted
+CSC is resident in HBM, and the cached prefix of a node is, slot for slot,
+the same ids as the start of its run there, so a hit is pure accounting:
+every draw reads ``row_index`` once.  A separate cache read pays only once
+``row_index`` no longer lives on the device (ROADMAP R2).
 
 Zero-degree nodes self-loop (counted as hits: no host access is needed).
 Sampling is with replacement; see DESIGN.md §3 for why this does not
@@ -39,17 +43,17 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class DeviceGraph:
-    """Graph structure as device arrays, with an optional adjacency cache.
+    """Graph structure as device arrays, with the adjacency cache's lengths.
 
     Without a cache, ``row_index`` is the original CSC order and
     ``cached_len`` is all zeros.  With a cache, ``row_index`` MUST be the
-    two-level-sorted copy (slots refer to sorted order on both paths).
+    two-level-sorted copy: node ``v``'s cached prefix is then its first
+    ``cached_len[v]`` slots there, so the whole sorted CSC being resident
+    makes ``cached_len`` all the device needs of the cache.
     """
 
     col_ptr: jax.Array  # int32[N+1]
-    row_index: jax.Array  # int32[E]   ("host"/UVA side)
-    cache_ptr: jax.Array  # int32[N+1]
-    cache_row_index: jax.Array  # int32[>=1] (padded to at least 1)
+    row_index: jax.Array  # int32[E]  resident in HBM
     cached_len: jax.Array  # int32[N]
 
     @property
@@ -57,10 +61,7 @@ class DeviceGraph:
         return self.col_ptr.shape[0] - 1
 
     def tree_flatten(self):
-        return (
-            (self.col_ptr, self.row_index, self.cache_ptr, self.cache_row_index, self.cached_len),
-            None,
-        )
+        return (self.col_ptr, self.row_index, self.cached_len), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -79,27 +80,22 @@ def device_graph(
     sorted_row_index: np.ndarray | None = None,
     adj_cache: AdjCache | None = None,
 ) -> DeviceGraph:
-    """Stage a CSC graph (and optionally its DCI adjacency cache) on device."""
-    n = graph.num_nodes
+    """Stage a CSC graph, and the hit lengths of its adjacency cache, on device.
+
+    The full (sorted) CSC goes to HBM whatever the cache holds, so of the
+    host :class:`AdjCache` only ``cached_len`` is uploaded: its cached ids
+    are a copy of what ``row_index`` already holds at the same slots.
+    """
     if adj_cache is not None:
         if sorted_row_index is None:
             raise ValueError("adjacency cache requires the two-level-sorted row_index")
-        row = sorted_row_index
-        cache_ptr = adj_cache.cache_ptr.astype(np.int32)
-        cache_row = adj_cache.cache_row_index
         cached_len = adj_cache.cached_len
     else:
-        row = graph.row_index if sorted_row_index is None else sorted_row_index
-        cache_ptr = np.zeros(n + 1, np.int32)
-        cache_row = np.empty(0, np.int32)
-        cached_len = np.zeros(n, np.int32)
-    if cache_row.shape[0] == 0:
-        cache_row = np.zeros(1, np.int32)  # keep gathers well-defined
+        cached_len = np.zeros(graph.num_nodes, np.int32)
+    row = graph.row_index if sorted_row_index is None else sorted_row_index
     return DeviceGraph(
         col_ptr=jnp.asarray(graph.col_ptr, jnp.int32),
         row_index=jnp.asarray(row, jnp.int32),
-        cache_ptr=jnp.asarray(cache_ptr, jnp.int32),
-        cache_row_index=jnp.asarray(cache_row, jnp.int32),
         cached_len=jnp.asarray(cached_len, jnp.int32),
     )
 
@@ -207,6 +203,11 @@ def sample_neighbors(
     where ``edge_slots`` are global positions ``col_ptr[v] + r`` used for
     visit counting during pre-sampling.
 
+    Each neighbor is read once, ``row_index[edge_slots]``; ``hits`` is the
+    adjacency cache's test ``r < cached_len[v]`` (Fig. 6c).  With the
+    sorted CSC resident in HBM a hit and a miss read the same id from the
+    same array, so the cache counts hits without a second gather.
+
     ``full_neighborhood=True`` (static) replaces the random draw with the
     deterministic enumeration ``r = arange(fanout) % deg``: when a seed's
     degree equals ``fanout`` every neighbor is taken exactly once, making
@@ -225,13 +226,8 @@ def sample_neighbors(
     else:
         r = jax.random.randint(key, (seeds.shape[0], fanout), 0, safe_deg[:, None])
     edge_slots = start[:, None] + r
-    host_nbr = g.row_index[edge_slots]
-
-    clen = g.cached_len[seeds]  # [S]
-    hit = r < clen[:, None]
-    cache_idx = g.cache_ptr[seeds][:, None] + jnp.minimum(r, jnp.maximum(clen - 1, 0)[:, None])
-    cache_nbr = g.cache_row_index[jnp.minimum(cache_idx, g.cache_row_index.shape[0] - 1)]
-    nbr = jnp.where(hit, cache_nbr, host_nbr)
+    nbr = g.row_index[edge_slots]
+    hit = r < g.cached_len[seeds][:, None]
 
     isolated = (deg == 0)[:, None]
     nbr = jnp.where(isolated, seeds[:, None], nbr)

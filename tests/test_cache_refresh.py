@@ -17,8 +17,11 @@ Load-bearing guarantees:
 
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from test_sampling import assert_blocks_match_reference, reference_graph
 
 from repro.core.cache import DualCache
 from repro.core.allocation import CacheAllocation
@@ -216,9 +219,13 @@ def test_dual_cache_refresh_bumps_epoch_and_applies_delta(small_dataset, rng):
     assert dc.allocation is new_alloc
     assert dc.feat_cached_rows * ds.feature_nbytes_per_row() <= new_alloc.feat_bytes
     assert dc.adj_cached_elements * 4 <= new_alloc.adj_bytes
-    # device adjacency array is padded (shape-stable across epochs); the
-    # logical prefix is what the budget pays for
-    assert dc.dgraph.cache_row_index.shape[0] >= dc.adj_cached_elements
+    # the device holds the new hit lengths, and samples from the resident
+    # sorted CSC exactly as the two-gather sampler over the new cache did
+    np.testing.assert_array_equal(np.asarray(dc.dgraph.cached_len), dc._adj_cache.cached_len)
+    assert_blocks_match_reference(
+        jax.random.PRNGKey(5), dc.dgraph, reference_graph(ds.graph, dc._sorted_row, dc._adj_cache),
+        jnp.asarray(ds.test_idx[:64], jnp.int32), (4, 3), dedup=True,
+    )
 
 
 def test_cacheless_dual_cache_rejects_refresh(small_dataset):

@@ -3,8 +3,9 @@
 The TPU compiler refuses what interpret mode accepts: tile-misaligned
 slices, scalar-memory overflow, programs that do not fit HBM.  These tests
 compile the gather kernels at the Table II widths and at real frontier row
-counts, and the GraphSAGE and GAT forwards at a full batch-1024 frontier, through
-``.lower(...).compile()`` on shapes alone.  The topology is described in a
+counts, the sampler over ogbn-products' CSC, and the GraphSAGE and GAT
+forwards at a full batch-1024 frontier, through ``.lower(...).compile()``
+on shapes alone.  The topology is described in a
 fixture (never at import), so every test worker collects the same tests
 and only the worker running this file loads the TPU compiler.
 """
@@ -13,12 +14,14 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.graph.sampling import DeviceGraph, sample_blocks
 from repro.kernels.cached_gather.kernel import (
     LANE,
     ROW_BLOCK,
@@ -31,6 +34,10 @@ HBM_BYTES = 16 * 2**30  # one v5e chip
 FANOUTS = (15, 10, 5)
 BATCH = 1024
 FRONTIER = BATCH * 16 * 11 * 6  # 1,081,344 input rows at fan-outs 15,10,5
+
+# ogbn-products' CSC (Table II stand-in): col_ptr, row_index, cached_len.
+PRODUCTS_CSC = (2_449_030, 61_200_000, 2_449_029)
+LAST_LAYER_DRAWS = BATCH * 6 * 11 * 15  # 1,013,760 draws at fan-out 15
 
 # (nodes, feature width): ogbn-products, the ogbn-papers100M width at a
 # node count one chip holds, Reddit (Table II).
@@ -121,3 +128,26 @@ def test_gat_forward_fits_one_chip(one_chip):
         functools.partial(forward, model="gat", fanouts=FANOUTS), params, feats,
         inverse_index=inverse,
     ).shape == (BATCH, 47)
+
+
+def test_sampler_reads_each_neighbor_once(one_chip):
+    """``sample_blocks`` at sage-products' shapes (batch 1024, fan-outs
+    15,10,5, dedup) gathers its 1,013,760 last-layer draws once, from the
+    resident ``row_index``: the adjacency cache adds a compare, not a
+    second gather."""
+    g = DeviceGraph(*(_sds((n,), jnp.int32, one_chip) for n in PRODUCTS_CSC))
+    assert len(jax.tree.leaves(g)) == 3
+    compiled = sample_blocks.lower(
+        _sds((2,), jnp.uint32, one_chip), g, _sds((BATCH,), jnp.int32, one_chip),
+        FANOUTS, dedup=True, dedup_pad_id=_sds((), jnp.int32, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}\n")]
+    gathers = [
+        line for line in entry.splitlines()
+        if f"= s32[{LAST_LAYER_DRAWS}]" in line
+        and "kind=kCustom" in line
+        and re.search(r'op_name="[^"]*gather"', line)
+    ]
+    assert len(gathers) == 1, gathers
