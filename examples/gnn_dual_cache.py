@@ -69,5 +69,4 @@ print(f"  shared  cache (one {BUDGET:,d} B DualCache, one presample): "
 print(f"  private caches ({STREAMS} x {BUDGET // STREAMS:,d} B, {STREAMS} presamples): "
       f"feat hit {private_hits / max(private_lookups, 1):.3f}")
 print("one shared budget-B cache beats N private B/N caches on hit rate, and")
-print("its presample/allocation/fill/compile cost is paid once, not N times")
-print("(benchmarks/bench_multistream.py quantifies the throughput uplift).")
+print("its presample/allocation/fill/compile cost is paid once, not N times.")

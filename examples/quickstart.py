@@ -4,6 +4,7 @@
 """
 
 from repro.graph import load_dataset
+from repro.graph.csc import BYTES_PER_ADJ_ELEMENT
 from repro.runtime.gnn_engine import GNNInferenceEngine
 
 # A scaled synthetic stand-in for Ogbn-products (Table II statistics).
@@ -19,8 +20,12 @@ for policy in ("dgl", "sci", "dci"):
     engine.prepare(policy, total_cache_bytes=2_000_000)
     report = engine.run(max_batches=8)
     s = report.summary()
+    # Bytes the cache misses moved, from the report's exact counts.
+    miss_bytes = (report.feat_lookups - report.feat_hits) * report.feat_row_bytes + (
+        report.adj_lookups - report.adj_hits
+    ) * BYTES_PER_ADJ_ELEMENT
     print(
         f"{policy:4s} | total {s['total_s']:6.3f}s | prep {s['prep_s']:6.3f}s | "
         f"adj hit {s['adj_hit_rate']:.2f} | feat hit {s['feat_hit_rate']:.2f} | "
-        f"modeled transfer {s['modeled_transfer_s']*1e3:7.3f}ms"
+        f"miss bytes {miss_bytes:,d}"
     )

@@ -93,7 +93,7 @@ def check_file(relpath: str) -> list[str]:
                     errors.append(f"{where}: `python -m {mod}` does not resolve in the tree")
 
     for mod in sorted(set(DOTTED_REF_RE.findall(text))):
-        # a ref may name an attribute (`benchmarks.common.emit`): the
+        # a ref may name an attribute (`repro.core.trace.Tracer`): the
         # module prefix resolving is what we can check statically
         if not (module_exists(mod) or module_exists(mod.rsplit(".", 1)[0])):
             errors.append(f"{relpath}: backticked module `{mod}` does not resolve")

@@ -19,8 +19,7 @@ workload before filling.  Two modes:
     presampling budget split evenly over the streams' seeds and merged
     (:func:`repro.core.presample.merge_stats`), so the one shared cache is
     allocated and filled for the union workload at no extra preprocessing
-    cost — the amortization bench_multistream.py measures against N
-    private per-stream preparations.
+    cost, where N private per-stream preparations would pay it N times.
 
   - ``dci``     the paper's system: Eq. 1 split + lightweight fill
   - ``sci``     single-cache baseline: whole budget to node features

@@ -16,7 +16,7 @@ this kind of per-stage timeline analysis):
 * :class:`NullTracer` — the disabled path.  Every method is a no-op and
   ``span`` returns a shared reusable context, so instrumented code costs
   one attribute check (``tracer.enabled``) or one no-op call per batch —
-  effectively free (gated in ``benchmarks/bench_trace.py``).
+  effectively free.
 * :class:`MetricsRegistry` — labelled counters / gauges / histograms
   (``feat_hit_rate{stream=...,epoch=...}``, ``request_latency_ms``),
   snapshotted into reports and dumpable as JSON or Prometheus text.
@@ -285,8 +285,7 @@ class NullTracer:
 
     ``span`` hands back one shared, stateless context object, so a fully
     instrumented hot loop executes a handful of attribute lookups and empty
-    calls per batch when tracing is disabled — the overhead gate in
-    ``benchmarks/bench_trace.py`` holds this under 1% of end-to-end time.
+    calls per batch when tracing is disabled.
     Call sites guard any non-trivial argument construction (building an
     ``args`` dict, reading queue depths) behind ``tracer.enabled``.
     """

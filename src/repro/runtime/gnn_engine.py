@@ -3,9 +3,8 @@
 Pipeline per mini-batch: sample blocks (adjacency cache aware) → gather
 input-frontier features (feature cache aware; RAIN reuses the previous
 batch instead) → run the GNN.  The engine times each stage exactly the way
-the paper decomposes Fig. 1/7, counts cache hits, and also reports a
-*modeled* transfer time using bandwidth constants so the CPU-only container
-can be projected onto the paper's PCIe/GPU (or a TPU host-HBM) topology.
+the paper decomposes Fig. 1/7 and counts cache hits and lookups; the
+counts say how many bytes a hit or a miss moved, never how long it took.
 
 Batch execution is delegated to the staged executor in
 :mod:`repro.runtime.pipeline`, controlled by the ``pipeline_depth`` knob:
@@ -52,35 +51,6 @@ __all__ = [
     "stream_stages",
     "summarize_epoch_counters",
 ]
-
-# Link speeds for the modeled-transfer projection (bytes/s).
-PCIE4_BW = 25e9  # paper's RTX 4090 host link (the UVA miss path)
-HBM_BW = 819e9  # TPU v5e HBM (the cache-hit path)
-
-ADJ_ENTRY_BYTES = 4  # one int32 neighbor id per adjacency lookup
-
-
-def modeled_transfer_seconds(
-    *,
-    feat_lookups: int,
-    feat_hits: int,
-    adj_lookups: int,
-    adj_hits: int,
-    feat_row_bytes: int,
-    slow_bw: float = PCIE4_BW,
-    fast_bw: float = HBM_BW,
-) -> float:
-    """Project byte movement onto a slow (miss) / fast (hit) link pair.
-
-    The one transfer model shared by the per-engine
-    :class:`InferenceReport` and the aggregate multi-stream
-    :class:`~repro.runtime.gnn_serve.ServeReport`."""
-    miss_bytes = (feat_lookups - feat_hits) * feat_row_bytes + (
-        adj_lookups - adj_hits
-    ) * ADJ_ENTRY_BYTES
-    hit_bytes = feat_hits * feat_row_bytes + adj_hits * ADJ_ENTRY_BYTES
-    return miss_bytes / slow_bw + hit_bytes / fast_bw
-
 
 @dataclasses.dataclass
 class InferenceReport:
@@ -158,18 +128,6 @@ class InferenceReport:
             return 1.0
         return self.feat_lookups / self.unique_rows
 
-    def modeled_transfer_seconds(self, slow_bw: float = PCIE4_BW, fast_bw: float = HBM_BW) -> float:
-        """Project byte movement onto a slow (miss) / fast (hit) link pair."""
-        return modeled_transfer_seconds(
-            feat_lookups=self.feat_lookups,
-            feat_hits=self.feat_hits,
-            adj_lookups=self.adj_lookups,
-            adj_hits=self.adj_hits,
-            feat_row_bytes=self.feat_row_bytes,
-            slow_bw=slow_bw,
-            fast_bw=fast_bw,
-        )
-
     def to_dict(self) -> dict:
         """The report as one JSON-safe dict: the summary metrics plus the
         resolved :class:`~repro.core.config.EngineConfig` echo.  Knobs are
@@ -195,7 +153,6 @@ class InferenceReport:
             "prep_s": round(self.prep_seconds, 4),
             "adj_hit_rate": round(self.adj_hit_rate, 4),
             "feat_hit_rate": round(self.feat_hit_rate, 4),
-            "modeled_transfer_s": round(self.modeled_transfer_seconds(), 6),
         }
         if self.config is not None:
             out["config"] = self.config.to_dict()
@@ -971,8 +928,8 @@ class GNNInferenceEngine:
 
         ``"auto"`` probes ONE serial batch against the prepared pipeline
         (after an untimed warmup, so compilation is excluded) and derives
-        the window from the measured compute:prep ratio — the same
-        decomposition bench_breakdown's serial rows report.  The probe
+        the window from the measured compute:prep ratio of that serial
+        batch's stage clock.  The probe
         uses its own RNG stream, so the run it sizes is unaffected; the
         result is cached on the engine — EXCEPT a degenerate probe (a
         ~zero prep lap, e.g. a cache-hit-everything first batch), which

@@ -21,8 +21,7 @@ stream's outputs, RNG sequence, and hit counters are bit-identical to
 running that stream's batches alone (tests/test_gnn_serve.py).  What
 sharing buys is systemic: one presample + allocation + fill + XLA compile
 amortized over all streams, and one budget-B cache serving everyone
-instead of N private B/N caches — the axes
-benchmarks/bench_multistream.py measures.
+instead of N private B/N caches.
 
 Online refresh (``refresh=RefreshConfig(...)``) closes the loop for
 long-lived serving: retire-path telemetry feeds a
@@ -53,10 +52,7 @@ from repro.core.retry import RetryExhausted, StageTimeout
 from repro.core.trace import resolve_tracer
 from repro.runtime.gnn_engine import (
     GNNInferenceEngine,
-    PCIE4_BW,
-    HBM_BW,
     StreamRuntime,
-    modeled_transfer_seconds,
     stream_stages,
     summarize_epoch_counters,
 )
@@ -300,27 +296,13 @@ class ServeReport:
         """Fraction of *offered* work that completed (degraded service
         counts as available — the request was answered, and marked).
         Offered = completed + shed + still-queued-at-exit; a fail-fast
-        run that dies early therefore scores near zero, which is exactly
-        the contrast bench_faults gates degraded mode against."""
+        run that dies early therefore scores near zero, where degraded
+        mode with retries stays near one."""
         completed = self.total_batches
         offered = completed + self.requests_shed + self.unserved
         if not offered:
             return 1.0
         return completed / offered
-
-    def modeled_transfer_seconds(self, slow_bw: float = PCIE4_BW, fast_bw: float = HBM_BW) -> float:
-        """Project aggregate byte movement onto a slow-miss / fast-hit link
-        pair (the model shared with
-        :class:`~repro.runtime.gnn_engine.InferenceReport`)."""
-        return modeled_transfer_seconds(
-            feat_lookups=self.feat_lookups,
-            feat_hits=self.feat_hits,
-            adj_lookups=self.adj_lookups,
-            adj_hits=self.adj_hits,
-            feat_row_bytes=self.feat_row_bytes,
-            slow_bw=slow_bw,
-            fast_bw=fast_bw,
-        )
 
     def summary(self) -> dict:
         out = {
@@ -334,7 +316,6 @@ class ServeReport:
             "throughput_seeds_per_s": round(self.throughput_seeds_per_s, 1),
             "adj_hit_rate": round(self.adj_hit_rate, 4),
             "feat_hit_rate": round(self.feat_hit_rate, 4),
-            "modeled_transfer_s": round(self.modeled_transfer_seconds(), 6),
             "p50_latency_s": round(self.p50_latency_s, 4),
             "p99_latency_s": round(self.p99_latency_s, 4),
             "per_stream": [s.summary() for s in self.streams],
@@ -819,8 +800,7 @@ class MultiStreamServer:
         executor under ``fault_policy != "shed"``) into a PARTIAL report:
         in-flight batches drain with full accounting, the error lands on
         ``report.error``, and unserved batches count against
-        ``report.availability`` — the fail-fast arm of bench_faults.
-        Real bugs always propagate."""
+        ``report.availability``.  Real bugs always propagate."""
         if not self.streams:
             raise RuntimeError("add_stream() at least one stream before run()")
         self._started = True

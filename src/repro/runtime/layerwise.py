@@ -56,7 +56,6 @@ from repro.graph.features import (
 from repro.graph.sampling import pow2_bucket
 from repro.kernels.cached_gather.kernel import ROW_BLOCK
 from repro.models import gnn as gnn_models
-from repro.runtime.gnn_engine import modeled_transfer_seconds
 from repro.runtime.pipeline import PipelinedExecutor, Stage
 from repro.utils.timing import StageClock
 
@@ -206,26 +205,6 @@ class LayerwiseReport:
     def embed_hit_rate(self) -> float:
         return self.embed_hits / max(self.embed_lookups, 1)
 
-    def modeled_transfer_seconds(self) -> float:
-        """Byte movement projected on the same slow/fast link pair as the
-        sampled engine — the machine-independent side of the crossover
-        benchmark (benchmarks/bench_layerwise.py).  Every layer moves the
-        edge list once (the chunk schedule's adjacency reads are
-        sequential host slices — all misses)."""
-        return modeled_transfer_seconds(
-            feat_lookups=self.feat_lookups,
-            feat_hits=self.feat_hits,
-            adj_lookups=self.num_layers * self.num_edges,
-            adj_hits=0,
-            feat_row_bytes=self.feat_row_bytes,
-        ) + modeled_transfer_seconds(
-            feat_lookups=self.embed_lookups,
-            feat_hits=self.embed_hits,
-            adj_lookups=0,
-            adj_hits=0,
-            feat_row_bytes=self.embed_row_bytes,
-        )
-
     def summary(self) -> dict:
         out = {
             "policy": self.policy,
@@ -244,7 +223,6 @@ class LayerwiseReport:
             "prep_s": round(self.prep_seconds, 4),
             "feat_hit_rate": round(self.feat_hit_rate, 4),
             "embed_hit_rate": round(self.embed_hit_rate, 4),
-            "modeled_transfer_s": round(self.modeled_transfer_seconds(), 6),
         }
         if self.config is not None:
             out["config"] = self.config.to_dict()

@@ -126,8 +126,7 @@ def uniform_seed_batches(dataset, *, n_batches: int, batch_size: int, seed: int 
 
 def flash_crowd_seed_batches(dataset, *, n_batches: int, batch_size: int, seed: int = 0):
     """Every batch a fresh permutation of ONE small fixed seed pool — the
-    concentrated hot set of benchmarks/bench_drift.py's phase B (shared so
-    "flash crowd" is the same workload there and here)."""
+    concentrated hot set a flash crowd sends."""
     rng = np.random.default_rng(seed)
     pool_size = min(batch_size, len(dataset.test_idx))
     pool = rng.choice(dataset.test_idx, size=pool_size, replace=False)
@@ -196,8 +195,7 @@ def burst_trace(
     queueing.  Round-robin interleaves the two, roughly doubling the
     burst's drain time (tail ≈ 2·B·service); EDF with a uniform SLO
     drains the burst's backlog first — its deadlines are earliest — for a
-    tail ≈ B·service, the ~2x p99 gap bench_multistream's tail gate
-    measures."""
+    tail ≈ B·service, about half round-robin's p99."""
     burst_batches = flash_crowd_seed_batches(
         dataset, n_batches=burst_requests, batch_size=batch_size, seed=seed
     )

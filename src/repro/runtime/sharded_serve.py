@@ -44,7 +44,7 @@ from repro.graph.csc import BYTES_PER_ADJ_ELEMENT
 from repro.graph.sampling import DedupFrontier
 from repro.graph.shard import ShardedFeatureStore, make_shard_plan
 from repro.launch.mesh import make_serving_mesh, serving_devices
-from repro.runtime.gnn_engine import StreamRuntime, modeled_transfer_seconds
+from repro.runtime.gnn_engine import StreamRuntime
 from repro.runtime.gnn_serve import MultiStreamServer, ServeReport
 from repro.runtime.pipeline import BatchContext
 
@@ -425,7 +425,6 @@ class ShardedServer(MultiStreamServer):
             # Adjacency traffic lands on the stream's sampling replica.
             adj_hits[rt.replica % k] += rt.adj_hits
             adj_lookups[rt.replica % k] += rt.adj_lookups
-        row_bytes = self.engine.dataset.feature_nbytes_per_row()
         rows_cached = self.sharded.store.shard_cached_rows()
         out = []
         for i in range(k):
@@ -438,16 +437,6 @@ class ShardedServer(MultiStreamServer):
                 "adj_lookups": int(adj_lookups[i]),
                 "gathered_rows": int(gathered[i]),
                 "prefetched_rows": int(prefetched[i]),
-                # Each shard drives its own HBM/PCIe link pair, so the
-                # mesh's modeled transfer time is the max over shards —
-                # the sharded-scaling metric bench_multistream gates.
-                "modeled_transfer_s": modeled_transfer_seconds(
-                    feat_lookups=int(lookups[i]),
-                    feat_hits=int(hits[i]),
-                    adj_lookups=int(adj_lookups[i]),
-                    adj_hits=int(adj_hits[i]),
-                    feat_row_bytes=row_bytes,
-                ),
             }
             if self.shard_allocations is not None:
                 a = self.shard_allocations[i]

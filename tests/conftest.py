@@ -1,7 +1,15 @@
 import numpy as np
 import pytest
 
+from repro.graph.csc import BYTES_PER_ADJ_ELEMENT
 from repro.graph.datasets import load_dataset
+
+
+def miss_bytes(rep) -> int:
+    """Bytes a report's cache misses moved, from its exact counts."""
+    return (rep.feat_lookups - rep.feat_hits) * rep.feat_row_bytes + (
+        rep.adj_lookups - rep.adj_hits
+    ) * BYTES_PER_ADJ_ELEMENT
 
 
 @pytest.fixture(scope="session")

@@ -24,6 +24,7 @@ import pytest
 from test_sampling import assert_blocks_match_reference, reference_graph
 
 from repro.core.cache import DualCache
+from repro.core.config import EngineConfig
 from repro.core.allocation import CacheAllocation
 from repro.core.telemetry import WorkloadTelemetry, merge_windows
 from repro.graph.csc import build_adj_cache, refresh_adj_cache, two_level_sort
@@ -31,6 +32,7 @@ from repro.graph.features import build_feature_cache, refresh_feature_cache, sel
 from repro.runtime.cache_refresh import CacheRefreshManager, RefreshConfig
 from repro.runtime.gnn_engine import GNNInferenceEngine, auto_pipeline_depth
 from repro.runtime.gnn_serve import MultiStreamServer, make_stream_batches
+from repro.runtime.request_queue import flash_crowd_seed_batches, uniform_seed_batches
 from repro.utils.timing import StageClock
 
 FANOUTS = (3, 2)
@@ -301,6 +303,35 @@ def test_engine_refresh_outputs_bit_identical_and_epochs_partition(small_dataset
     # per-epoch counters partition the lifetime counters exactly
     assert r1.epoch_hits is not None and len(r1.epoch_hits) >= 2
     assert sum(v["batches"] for v in r1.epoch_hits.values()) == r1.num_batches
+
+
+def test_refresh_recovers_feature_hits_after_flash_crowd_shift(small_dataset):
+    """Prepare on uniform traffic, then shift to a flash crowd (every batch
+    one small seed pool): the interval refresh re-ranks the feature cache
+    toward the new hot set and hits more than the frozen cache, by delta
+    re-fills alone.  SCI and a sub-byte split step hold the allocation, so
+    the wall-clock Eq. 1 re-run cannot move budget; the hits are counts."""
+    bs = 64
+    eng = GNNInferenceEngine(small_dataset, fanouts=(8,), batch_size=bs)
+    uniform = uniform_seed_batches(small_dataset, n_batches=8, batch_size=bs, seed=0)
+    crowd = flash_crowd_seed_batches(small_dataset, n_batches=8, batch_size=bs, seed=1)
+    eng.prepare("sci", total_cache_bytes=50_000, n_presample=4)
+    refresh = RefreshConfig(
+        mode="interval", interval_batches=2, history_decay=0.3, max_split_step=1e-9
+    )
+    serial = EngineConfig(pipeline_depth=1)
+    post = {}
+    # Static first: a refreshed pass mutates the shared caches in place.
+    for label, cfg in (("static", None), ("refreshed", refresh)):
+        eng.run(batches=uniform, config=serial, refresh=cfg)
+        post[label] = eng.run(batches=crowd, config=serial, refresh=cfg)
+    static, refreshed = post["static"], post["refreshed"]
+    assert static.feat_lookups == refreshed.feat_lookups
+    assert refreshed.feat_hits > static.feat_hits
+    assert refreshed.refresh_events
+    for e in refreshed.refresh_events:
+        assert e.delta.allocation.adj_bytes == 0
+        assert e.delta.feat.rows_kept > 0
 
 
 def test_engine_refresh_off_is_default_path(small_dataset):

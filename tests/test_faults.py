@@ -450,6 +450,24 @@ def test_degraded_mode_serves_cache_only_when_miss_path_is_down(small_dataset):
     assert rep.feat_lookups > 0 and rep.feat_hits > 0
 
 
+def test_degraded_retry_holds_availability_where_fail_fast_collapses(small_dataset):
+    """One seeded plan of transient miss-path faults, two policies: the
+    fail-fast serve dies at the first fault and answers at most half the
+    offer, while retry plus degraded mode answers all of it, some requests
+    retried and one served cache-only.  The plan is a pure function of
+    (seed, call index), so both availabilities are exact."""
+    eng = _shared_engine(small_dataset)
+    queues = _queues(small_dataset)
+    plan = FaultPlan(seed=2, rules=(FaultRule("host_fetch", probability=0.3),))
+    cfg = ServeConfig(engine=EngineConfig(pipeline_depth=2))
+    _, ff, _ = _serve(eng, queues, cfg=cfg, injector=FaultInjector(plan), raise_on_error=False)
+    dg_cfg = cfg.replace(**_fast_retry(degraded_mode=True))
+    _, dg, _ = _serve(eng, queues, cfg=dg_cfg, injector=FaultInjector(plan))
+    assert ff.error is not None and ff.availability <= 0.5
+    assert dg.availability >= 0.99 and dg.requests_shed == 0
+    assert dg.requests_retried > 0 and dg.requests_degraded > 0
+
+
 def test_kernel_faults_fall_back_to_the_table_route(small_dataset):
     """kernel_gather down: every gather reroutes to the table route, which
     is bit-identical by the kernel-parity contract, so nothing is degraded

@@ -14,6 +14,7 @@ The load-bearing guarantees:
 
 import numpy as np
 import pytest
+from conftest import miss_bytes
 
 from repro.core.presample import merge_stats, run_presampling
 from repro.runtime.gnn_engine import GNNInferenceEngine
@@ -143,7 +144,8 @@ def test_aggregate_accounting_sums_streams(small_dataset):
     assert rep.total_seeds == rep.total_batches * BATCH
     assert 0 < rep.feat_hit_rate <= 1
     assert rep.throughput_seeds_per_s > 0
-    assert rep.modeled_transfer_seconds() > 0
+    assert rep.feat_row_bytes == small_dataset.feature_nbytes_per_row()
+    assert miss_bytes(rep) > 0
     summary = rep.summary()
     assert summary["streams"] == 3 and len(summary["per_stream"]) == 3
 
@@ -232,6 +234,33 @@ def test_shared_prepare_splits_presample_budget(small_dataset):
     # not multiplied by them and not truncated by integer division
     assert pipe.presample.n_batches == 8
     assert pipe.caches.allocation.total_bytes == 200_000
+
+
+def test_shared_cache_outhits_private_slices(small_dataset):
+    """One budget-B cache prepared from the union presample hits more of
+    the streams' feature lookups than N private B/N caches, each prepared
+    from its own stream's presample and serving that stream alone.  ``sci``
+    gives the whole budget to features, so no wall-clock Eq. 1 split
+    enters the comparison and the counts are exact."""
+    queues = _queues(small_dataset)
+    server = MultiStreamServer(_shared_engine(small_dataset, "sci"), depth=1)
+    for i, q in enumerate(queues):
+        server.add_stream(q, seed=STREAM_SEEDS[i])
+    shared = server.run()
+    hits = lookups = 0
+    for i, q in enumerate(queues):
+        eng = GNNInferenceEngine(
+            small_dataset, fanouts=FANOUTS, batch_size=BATCH, seed=STREAM_SEEDS[i]
+        )
+        eng.prepare(
+            "sci",
+            total_cache_bytes=KW["total_cache_bytes"] // len(queues),
+            n_presample=KW["n_presample"],
+        )
+        rep = eng.run(batches=list(q), pipeline_depth=1)
+        hits, lookups = hits + rep.feat_hits, lookups + rep.feat_lookups
+    assert lookups == shared.feat_lookups
+    assert shared.feat_hits > hits
 
 
 # -------------------------------------------------------------------- errors

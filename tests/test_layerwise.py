@@ -291,7 +291,11 @@ def test_engine_dispatch_and_report():
     n, e = ds.num_nodes, ds.graph.num_edges
     assert rep.feat_lookups == n + e
     assert rep.embed_lookups == (rep.num_layers - 1) * (n + e)
-    assert rep.modeled_transfer_seconds() > 0
+    assert rep.num_edges == e
+    assert rep.feat_row_bytes == ds.feature_nbytes_per_row()
+    assert 0 <= rep.feat_hits <= rep.feat_lookups
+    assert 0 <= rep.embed_hits <= rep.embed_lookups
+    assert rep.embed_row_bytes > 0
 
 
 def test_layerwise_allocation_mapping():

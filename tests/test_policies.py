@@ -25,6 +25,8 @@ def test_policy_end_to_end(small_dataset, policy):
         assert rep.adj_hit_rate > 0
     if policy in ("dci", "sci", "ducati"):
         assert rep.feat_hit_rate > 0
+    if policy == "aci":  # the whole budget to adjacency: no feature row cached
+        assert rep.feat_hits == 0 and rep.adj_hit_rate > 0
 
 
 def test_dci_allocation_follows_eq1(small_dataset):
@@ -40,6 +42,29 @@ def test_sci_all_budget_to_features(small_dataset):
     assert a.adj_bytes == 0
     assert a.feat_bytes == KW["total_cache_bytes"]
     assert pipe.caches.adj_cached_elements == 0
+
+
+def test_sci_feature_hits_monotone_in_capacity(small_dataset):
+    """Fig. 2: a larger feature-only budget never hits less.  SCI takes no
+    Eq. 1 split, so the hits are exact counts, free of the wall clock."""
+    hits = []
+    for cap in (0, 20_000, 80_000, 320_000, 1_280_000):
+        eng = GNNInferenceEngine(small_dataset, fanouts=(3, 2), batch_size=64)
+        eng.prepare("sci", total_cache_bytes=cap, n_presample=2)
+        hits.append(eng.run(max_batches=4).feat_hits)
+    assert hits[0] == 0
+    assert hits == sorted(hits) and hits[-1] > hits[1]
+
+
+def test_sci_hit_rate_stable_by_eight_presample_batches(small_dataset):
+    """Fig. 11: eight pre-sampling batches already rank the hot rows about
+    as well as sixteen do (SCI, so no wall-clock split enters)."""
+    rates = {}
+    for n in (8, 16):
+        eng = GNNInferenceEngine(small_dataset, fanouts=(3, 2), batch_size=64)
+        eng.prepare("sci", total_cache_bytes=100_000, n_presample=n)
+        rates[n] = eng.run(max_batches=8).feat_hit_rate
+    assert abs(rates[16] - rates[8]) < 0.05
 
 
 def test_rain_batch_order_is_permutation(small_dataset):

@@ -104,6 +104,34 @@ def test_hit_rate_in_unit_interval(small_dataset, fanout, n_seeds, seed):
     assert 0.0 <= rate <= 1.0
 
 
+def test_redundant_loading_grows_with_fanout(small_dataset):
+    """Tab. I: the nodes loaded per served seed grow with fan-out and do not
+    grow with batch size; within one batch the same node is loaded more
+    than once, and that duplication also grows with fan-out."""
+    ds = small_dataset
+    g = device_graph(ds.graph)
+    fanouts = ((2, 2, 2), (8, 4, 2), (15, 10, 5))
+    loaded_per_seed, duplication = {}, {}
+    for bs in (64, 256):
+        seeds_all = ds.test_idx[: 4 * bs].reshape(4, bs)
+        for fo in fanouts:
+            key = jax.random.PRNGKey(0)
+            loaded = unique = 0
+            for seeds in seeds_all:
+                key, sub = jax.random.split(key)
+                block = sample_blocks(sub, g, jnp.asarray(seeds), fo, dedup=True)
+                loaded += int(block.input_nodes.shape[0])
+                unique += int(block.dedup.num_unique)
+            loaded_per_seed[bs, fo] = loaded / seeds_all.size
+            duplication[bs, fo] = loaded / unique
+    for bs in (64, 256):
+        lps = [loaded_per_seed[bs, fo] for fo in fanouts]
+        dup = [duplication[bs, fo] for fo in fanouts]
+        assert lps[0] < lps[1] < lps[2]
+        assert 1.0 < dup[0] < dup[1] < dup[2]
+    assert loaded_per_seed[256, (15, 10, 5)] <= loaded_per_seed[64, (15, 10, 5)]
+
+
 def test_dual_cache_build(small_dataset):
     ds = small_dataset
     rng = np.random.default_rng(0)
